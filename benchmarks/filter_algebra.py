@@ -57,6 +57,9 @@ def _timed(fn, repeats=3):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--corpus", type=int, default=12000)
     ap.add_argument("--train-queries", type=int, default=384)

@@ -69,6 +69,9 @@ def assert_bit_identical(a, b):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=512,
                     help="mixed-plan queries for the telemetry run")

@@ -242,6 +242,9 @@ def run(quick=False):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small world, no artifact write (CI smoke)")

@@ -42,6 +42,9 @@ PLAN_NAMES = ("scan", "traverse", "widen")
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--corpus", type=int, default=12000)
     ap.add_argument("--train-queries", type=int, default=384)

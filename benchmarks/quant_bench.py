@@ -126,6 +126,9 @@ def traversal_wall(engines, cfg, queries, filt, budget, repeats):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--corpus", type=int, default=16000)
     ap.add_argument("--dim", type=int, default=64)

@@ -19,6 +19,9 @@ def _row(name, us, **derived):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma list: fig3,fig56,table3,fig7,fig8,fig910")

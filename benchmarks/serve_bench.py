@@ -157,6 +157,9 @@ def _timed(fn):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=224)
     ap.add_argument("--corpus", type=int, default=12000)

@@ -77,19 +77,12 @@ def _timed(fn, repeats=REPEATS):
     return best
 
 
-def _random_regular(ns, degree, rng):
-    """Self-loop-free random-regular neighbor lists (shard-local ids)."""
-    nb = rng.integers(0, ns, size=(ns, degree)).astype(np.int32)
-    rows = np.arange(ns, dtype=np.int32)[:, None]
-    nb = np.where(nb == rows, (nb + 1) % ns, nb)
-    return nb
-
-
 def _world(n, dim, degree, n_shards, seed=0):
     """Dataset + sharded random-regular graph (see module docstring on why
     the graphs are random: this bench times machinery, not recall)."""
     from repro.data.synthetic import AttributedDataset
-    from repro.index.graph import GraphIndex, ShardedGraphIndex
+    from repro.index.graph import (GraphIndex, ShardedGraphIndex,
+                                   random_regular_neighbors)
 
     rng = np.random.default_rng(seed)
     vectors = rng.standard_normal((n, dim), dtype=np.float32)
@@ -104,7 +97,7 @@ def _world(n, dim, degree, n_shards, seed=0):
         cluster_ids=np.zeros(n, np.int32),
     )
     ns = n // n_shards
-    shards = [GraphIndex(neighbors=_random_regular(ns, degree, rng),
+    shards = [GraphIndex(neighbors=random_regular_neighbors(ns, degree, rng),
                          entry_point=0, dim=dim, shard=s, offset=s * ns)
               for s in range(n_shards)]
     queries = vectors[rng.integers(0, n, 64)] + 0.05 * rng.standard_normal(
@@ -272,6 +265,9 @@ def run(quick=False):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small world, no artifact write (CI smoke)")

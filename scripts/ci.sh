@@ -11,12 +11,13 @@
 set -uo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
-# The image ships libtpu; without this, jax may spend minutes probing for
-# TPU workers before falling back to CPU (override to run on real TPUs).
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
+# The suite runs on the CPU: Pallas kernels in interpret mode or as their
+# jnp twins (kernels/ops.py). The chip is driven by `python chip_smoke.py`
+# on the TPU host, not by this script.
+export JAX_PLATFORMS=cpu
 
 CHUNKS=(
-  "tests/test_kernels.py tests/test_property.py"
+  "tests/test_kernels.py tests/test_property.py tests/test_tpu_compile.py"
   "tests/test_filters.py"
   "tests/test_backends.py"
   "tests/test_quant.py"

@@ -29,6 +29,7 @@ constructor (`SearchEngine.build(ds, graph, backend="pallas")`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,38 @@ def make_search_mesh(devices=None) -> Mesh | None:
 _pad_batch = pad_lanes
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh", "bspec"))
+def _batch_mesh_search(cfg, mesh, bspec, q, prog, base, attrs, nb, budgets,
+                       entry, state, gt, quant):
+    """One traversal per device over the batch axis, as one program (an
+    eager `shard_map` would trace and dispatch its body anew each call)."""
+    rep = P()
+    args = [q, prog, base, attrs, nb, budgets, entry]
+    specs = [bspec, bspec, rep, rep, rep, bspec, rep]
+    has_state, has_gt = state is not None, gt is not None
+    has_quant = quant is not None
+    if has_state:
+        args.append(state)
+        specs.append(bspec)
+    if has_gt:
+        args.append(gt)
+        specs.append(bspec)
+    if has_quant:
+        args.append(quant)          # index data: replicated like the vectors
+        specs.append(rep)
+
+    def fn(*a):
+        qq, qa, base, at, nb, bud, ep = a[:7]
+        st = a[7] if has_state else None
+        g = a[7 + has_state] if has_gt else None
+        qt = a[7 + has_state + has_gt] if has_quant else None
+        return run_search(cfg, qq, qa, base, at, nb, bud, ep,
+                          state=st, gt_dist=g, quant=qt)
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=bspec, check_vma=False)(*args)
+
+
 @dataclasses.dataclass
 class SearchEngine:
     base_vectors: jnp.ndarray   # [N, d]
@@ -81,6 +114,10 @@ class SearchEngine:
                                 # rerank; when set (host tier), base_vectors
                                 # is a [N, 0] placeholder — only its row
                                 # count is read in compressed mode
+    # precision -> (source arrays, (rows, aux, nbrs)): the persistent
+    # kernel's lane-padded HBM operands, see `persistent_operands`
+    _persistent_ops: dict = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
 
     @classmethod
     def build(cls, ds: AttributedDataset, graph: GraphIndex,
@@ -169,6 +206,25 @@ class SearchEngine:
         if vals.ndim == 1:  # hand-built engines may carry a single channel
             vals = vals[:, None]
         return self.label_attrs, vals
+
+    def persistent_operands(self, precision: str):
+        """(rows, aux, nbrs) for the persistent multi-step kernel at
+        `precision`: per-node stores widened to 128-lane rows
+        (kernels/persistent_step.py). Packed on the first kernel search
+        and kept for the engine's lifetime, so probe and resume do not
+        re-pack them; repacked only if a source array was replaced."""
+        from repro.kernels.persistent_step import build_persistent_operands
+
+        src = (self.base_vectors, self.label_attrs, self.value_attrs,
+               self.neighbors, self.quant)
+        hit = self._persistent_ops.get(precision)
+        if hit is None or any(a is not b for a, b in zip(hit[0], src)):
+            labels, values = self._attrs()
+            hit = (src, build_persistent_operands(
+                precision, self.base_vectors, labels, values, self.neighbors,
+                self.quant))
+            self._persistent_ops[precision] = hit
+        return hit[1]
 
     def compile(self, filt) -> FilterProgram:
         """Lower FilterSpec | Expr | sequence[Expr] to a device program."""
@@ -272,6 +328,7 @@ class SearchEngine:
                     cfg, q, prog, self.base_vectors, attrs, self.neighbors,
                     budgets, self.entry_point, state=state, gt_dist=gt,
                     quant=quant, tracer=tracer, trace_id=trace_id,
+                    kernel_operands=self.persistent_operands,
                 )
             return run_search(
                 cfg, q, prog, self.base_vectors, attrs, self.neighbors,
@@ -284,8 +341,6 @@ class SearchEngine:
     # ---------------------------------------------------------- sharded ----
     def _search_sharded(self, cfg, q, prog, attrs, budgets, state, gt,
                         quant=None):
-        from jax.experimental.shard_map import shard_map
-
         mesh = self.mesh
         ndev = int(np.prod(list(mesh.shape.values())))
         b = q.shape[0]
@@ -297,7 +352,6 @@ class SearchEngine:
             # replicating the whole batch on every device. (b + pad is a
             # multiple of ndev, hence of the first-axis size.)
             bspec = P(mesh.axis_names[0])
-        rep = P()
 
         q = _pad_batch(q, pad)
         # program rows pad with all-zero (match-nothing) clauses — inert
@@ -306,35 +360,9 @@ class SearchEngine:
         budgets = _pad_batch(budgets, pad)  # 0-budget lanes stop immediately
         state = None if state is None else _pad_batch(state, pad)
         gt = None if gt is None else _pad_batch(gt, pad)
-
-        args = [q, prog, self.base_vectors, attrs, self.neighbors, budgets]
-        specs = [bspec, bspec, rep, rep, rep, bspec]
-        has_state, has_gt = state is not None, gt is not None
-        has_quant = quant is not None
-        if has_state:
-            args.append(state)
-            specs.append(bspec)
-        if has_gt:
-            args.append(gt)
-            specs.append(bspec)
-        if has_quant:
-            args.append(quant)      # index data: replicated like the vectors
-            specs.append(rep)
-
-        entry = self.entry_point
-
-        def fn(*a):
-            qq, qa, base, at, nb, bud = a[:6]
-            st = a[6] if has_state else None
-            g = a[6 + has_state] if has_gt else None
-            qt = a[6 + has_state + has_gt] if has_quant else None
-            return run_search(cfg, qq, qa, base, at, nb, bud, entry,
-                              state=st, gt_dist=g, quant=qt)
-
-        out = shard_map(
-            fn, mesh=mesh, in_specs=tuple(specs), out_specs=bspec,
-            check_rep=False,
-        )(*args)
+        out = _batch_mesh_search(cfg, mesh, bspec, q, prog, self.base_vectors,
+                                 attrs, self.neighbors, budgets,
+                                 jnp.int32(self.entry_point), state, gt, quant)
         if pad:
             out = jax.tree.map(lambda a: a[:b], out)
         return out

@@ -176,16 +176,17 @@ def _run_search_impl(
 # every probe→resume / preemption slice. (Donation inside a traced context —
 # e.g. under the sharded engine's shard_map — is ignored by JAX, which is
 # exactly the safe behavior.)
+# `entry_point` is traced, not static: the shards of a sharded engine have
+# different entry nodes and share one compiled program.
 run_search = functools.partial(
     jax.jit,
-    static_argnames=("cfg", "entry_point"),
+    static_argnames=("cfg",),
     donate_argnames=("state",),
 )(_run_search_impl)
 
 # Untraced entry for callers already inside a traced context (the sharded
 # engine's shard_map body runs one traversal per local index shard, with a
-# *traced* per-shard entry point — init_state only touches entry_point via
-# jnp ops, so tracing it is safe where run_search's static_argnames aren't).
+# traced per-shard entry point).
 run_search_impl = _run_search_impl
 
 
@@ -199,20 +200,33 @@ run_search_impl = _run_search_impl
 # quantity the serving metrics report (a ⌈steps/spl⌉ estimate undercounts:
 # probe phases dispatch once per snapshot, and compaction relaunches split
 # what a step count would merge). Lifetime counters, read via deltas.
-_DISPATCH_COUNTERS = {"launches": 0, "compactions": 0, "steps": 0}
+# `bodies` counts launches per body: "<mode>:kernel" (the VMEM-resident
+# multi-step kernel) or "<mode>:xla" (the jitted launch loop of steps).
+_DISPATCH_COUNTERS = {"launches": 0, "compactions": 0, "steps": 0,
+                      "bodies": {}}
 
 
 def dispatch_counters() -> dict:
     """Snapshot of lifetime persistent-driver dispatch counters:
     `launches` (device dispatches), `compactions` (launches at reduced
-    lane width), `steps` (lockstep trips actually advanced). Callers
-    measure work by differencing two snapshots."""
-    return dict(_DISPATCH_COUNTERS)
+    lane width), `steps` (lockstep trips actually advanced), and
+    `bodies` (launches per "<mode>:kernel|xla" body). Callers measure
+    work by differencing two snapshots."""
+    return dict(_DISPATCH_COUNTERS,
+                bodies=dict(_DISPATCH_COUNTERS["bodies"]))
+
+
+def _count_launch(body: str, steps: int, compacted: bool = False):
+    _DISPATCH_COUNTERS["launches"] += 1
+    _DISPATCH_COUNTERS["compactions"] += int(compacted)
+    _DISPATCH_COUNTERS["steps"] += steps
+    bodies = _DISPATCH_COUNTERS["bodies"]
+    bodies[body] = bodies.get(body, 0) + 1
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "entry_point", "mode", "use_kernel"),
+    static_argnames=("cfg", "mode", "use_kernel"),
     donate_argnames=("state",),
 )
 def _persistent_launch(
@@ -283,6 +297,7 @@ def run_search_persistent(
     quant=None,
     tracer=None,
     trace_id: str = "",
+    kernel_operands=None,
 ) -> SearchState:
     """Eager launch-loop driver for persistent backends (single device).
 
@@ -308,6 +323,10 @@ def run_search_persistent(
     dispatch + the `hops` readback the driver performs anyway — tracing
     adds no device synchronization and the state stream is untouched, so
     results are bit-identical with tracing on or off.
+
+    `kernel_operands(precision)` returns the kernel's packed (rows, aux,
+    nbrs) HBM operands; the engine passes `SearchEngine.persistent_operands`,
+    which keeps them for its lifetime. Only called where the kernel runs.
     """
     from repro.obs.trace import as_tracer
 
@@ -315,14 +334,15 @@ def run_search_persistent(
     qprep = _make_qprep(cfg, queries, quant)
     b = int(queries.shape[0])
     budgets = jnp.broadcast_to(jnp.asarray(budgets, jnp.int32), (b,))
-    use_kernel = (jax.default_backend() == "tpu" and cfg.mode == "post")
+    from repro.kernels.ops import interpret_mode
+
+    # the multi-step kernel covers post mode; pre/widen run the jitted
+    # launch loop over single steps (`dispatch_counters()["bodies"]`)
+    use_kernel = cfg.mode == "post" and not interpret_mode()
+    body = f"{cfg.mode}:{'kernel' if use_kernel else 'xla'}"
     rows = aux = None
     if use_kernel:
-        from repro.kernels.persistent_step import build_persistent_operands
-
-        rows, aux = build_persistent_operands(
-            cfg.precision or "float32", base_vectors, attrs[0], attrs[1],
-            quant)
+        rows, aux, neighbors = kernel_operands(cfg.precision or "float32")
 
     mode = "init" if state is None else "resume"
     hops0 = 0 if state is None else np.asarray(state.hops)
@@ -334,8 +354,7 @@ def run_search_persistent(
             use_kernel=use_kernel)
         it = int((np.asarray(state.hops) - hops0).max(initial=0))
         sp.set(steps=it)
-    _DISPATCH_COUNTERS["launches"] += 1
-    _DISPATCH_COUNTERS["steps"] += it
+    _count_launch(body, it)
 
     min_w = min(8, b)  # ladder floor bounds the retrace count to O(log B)
     while it < cfg.max_steps:
@@ -355,8 +374,7 @@ def run_search_persistent(
                 d = int((np.asarray(state.hops) - hops0).max(initial=0))
                 sp.set(steps=d)
             it += d
-            _DISPATCH_COUNTERS["launches"] += 1
-            _DISPATCH_COUNTERS["steps"] += d
+            _count_launch(body, d)
             continue
         pad = w - int(sel.size)
         tr.emit("compact", trace_id, from_width=b, to_width=w,
@@ -375,8 +393,6 @@ def run_search_persistent(
             d = int((np.asarray(out.hops) - hops0).max(initial=0))
             sp.set(steps=d)
         it += d
-        _DISPATCH_COUNTERS["launches"] += 1
-        _DISPATCH_COUNTERS["compactions"] += 1
-        _DISPATCH_COUNTERS["steps"] += d
+        _count_launch(body, d, compacted=True)
         state = put_lanes(state, out, sel_p)
     return state

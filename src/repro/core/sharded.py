@@ -31,16 +31,14 @@ mesh in both paths, by the same jitted reduction over the same stacked
 values.
 
 The loop path is bit-identical to the single-device engine at every
-precision; the mesh path is bit-identical at float32. Quantized (int8/pq)
-distances under the mesh path can differ from the loop path by 1 ulp:
-XLA's SPMD pipeline fuses the ADC float tail (qn + xn − 2·s·dot)
-differently inside `shard_map` than under plain `jit`, contracting the
-mul/subtract into an FMA in one context but not the other. This is a
-compiler codegen property, not a reduction-order issue — it reproduces on
-a 1-device mesh with fully replicated operands, and survives
-`optimization_barrier` pinning and --xla_cpu_enable_fast_math=false — so
-the quantized mesh-path contract is "allclose within 1 ulp" (candidate
-*sets* still match; only distance bits wobble).
+precision. The mesh path runs as one jitted program (`_mesh_pools`); run
+eagerly, `shard_map` executes its body op by op, unfused, which both
+recompiled every op on every call and moved distances by a few ulp. Jitted,
+it is bit-identical to the loop path on XLA:CPU at float32, int8 and pq.
+The quantized mesh-path contract stays "within 1 ulp" (ids and counters
+exact): a compiler may fuse the ADC float tail (qn + xn − 2·s·dot)
+differently under SPMD partitioning than under plain `jit`, contracting the
+mul/subtract into an FMA in one context but not the other.
 
 Accounting contract (what keeps the estimator, planner, probe→resume and
 EXPLAIN working unchanged):
@@ -222,6 +220,74 @@ def merge_shard_states(stacked: SearchState, offsets) -> SearchState:
 def merge_with_pools(stacked: SearchState, rd, rp, cd, cp) -> SearchState:
     """`merge_shard_states` with externally merged (butterfly) pools."""
     return _merged_from(stacked, rd, rp, cd, cp)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+def _mesh_pools(cfg, mesh, q, prog, sbud, stx, state, gt):
+    """The mesh path's traversals and butterfly merge as one program.
+
+    Under jit, not eagerly: an eager `shard_map` runs its body op by op
+    and compiles every op again on every call. Returns the stacked
+    per-shard states and the merged pools (rd, rp, cd, cp)."""
+    dindex = int(mesh.shape[INDEX_AXIS])
+    nloc = stx["entries"].shape[0] // dindex   # shards per index device
+    k, m = cfg.k, cfg.queue_size
+    bspec = P(BATCH_AXIS)
+    ispec = P(INDEX_AXIS)
+    bsspec = P(BATCH_AXIS, INDEX_AXIS)
+    has_state, has_gt = state is not None, gt is not None
+    has_quant = cfg.precision != "float32"
+
+    args = [q, prog, sbud, stx["base"], stx["labels"], stx["values"],
+            stx["neighbors"], stx["entries"], stx["offsets"]]
+    specs = [bspec, bspec, bspec, ispec, ispec, ispec, ispec, ispec, ispec]
+    if has_state:
+        args.append(state)
+        specs.append(bsspec)
+    if has_gt:
+        args.append(gt)
+        specs.append(bspec)
+    if has_quant:
+        args.append(stx["quant"])
+        specs.append(ispec)
+
+    def fn(qq, qa, bud, base, labels, values, nb, entries, offs, *rest):
+        j = 0
+        st = rest[j] if has_state else None
+        j += has_state
+        g = rest[j] if has_gt else None
+        j += has_gt
+        qt = rest[j] if has_quant else None
+        outs = []
+        for jj in range(nloc):                # static unroll: local shards
+            stj = (None if st is None
+                   else jax.tree.map(lambda a: a[:, jj], st))
+            qtj = (None if qt is None
+                   else jax.tree.map(lambda a: a[jj], qt))
+            outs.append(run_search_impl(
+                cfg, qq, qa, base[jj], (labels[jj], values[jj]), nb[jj],
+                bud, entries[jj], state=stj, gt_dist=g, quant=qtj))
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *outs)
+        # local merge tree on the global position space (shard0 keys this
+        # device's pools into the virtual concatenation of all S)
+        shard0 = jax.lax.axis_index(INDEX_AXIS) * nloc
+        off = offs[None, :, None]
+        res_g = jnp.where(stacked.res_idx >= 0, stacked.res_idx + off, -1)
+        rd, rp, ro = merge_stacked(stacked.res_dist, res_g, k, shard0=shard0)
+        cpay = pack_payload(
+            jnp.where(stacked.cand_idx >= 0, stacked.cand_idx + off, -1),
+            stacked.cand_exp, stacked.cand_valid)
+        cd, cp, co = merge_stacked(stacked.cand_dist, cpay, m, shard0=shard0)
+        # cross-device butterfly: after log2(dindex) rounds every index
+        # device holds the identical global pools
+        rd, rp, ro = butterfly_merge(rd, rp, ro, k, INDEX_AXIS, dindex)
+        cd, cp, co = butterfly_merge(cd, cp, co, m, INDEX_AXIS, dindex)
+        return stacked, rd, rp, cd, cp
+
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(specs),
+        out_specs=(bsspec, bspec, bspec, bspec, bspec), check_vma=False,
+    )(*args)
 
 
 @dataclasses.dataclass
@@ -509,86 +575,17 @@ class ShardedSearchEngine:
         return self._stacked
 
     def _search_mesh(self, cfg, q, prog, sbud, state, gt):
-        from jax.experimental.shard_map import shard_map
-
-        mesh = self.mesh
-        ddata = int(mesh.shape[BATCH_AXIS])
-        dindex = int(mesh.shape[INDEX_AXIS])
-        s = self.n_shards
-        nloc = s // dindex                    # shards per index device
-        k, m = cfg.k, cfg.queue_size
-        stx = self._stacked_arrays()
-
         b = q.shape[0]
-        pad = (-b) % ddata
+        pad = (-b) % int(self.mesh.shape[BATCH_AXIS])
         q = pad_lanes(q, pad)
         prog = pad_lanes(prog, pad)
         sbud = pad_lanes(sbud, pad)           # 0-budget pad lanes are inert
         st_in = None if state is None else pad_lanes(state.shard, pad)
         gt = None if gt is None else pad_lanes(gt, pad)
-
-        bspec = P(BATCH_AXIS)
-        ispec = P(INDEX_AXIS)
-        bsspec = P(BATCH_AXIS, INDEX_AXIS)
-        has_state, has_gt = st_in is not None, gt is not None
-        has_quant = cfg.precision != "float32"
-
-        args = [q, prog, sbud, stx["base"], stx["labels"], stx["values"],
-                stx["neighbors"], stx["entries"], stx["offsets"]]
-        specs = [bspec, bspec, bspec, ispec, ispec, ispec, ispec, ispec,
-                 ispec]
-        if has_state:
-            args.append(st_in)
-            specs.append(bsspec)
-        if has_gt:
-            args.append(gt)
-            specs.append(bspec)
-        if has_quant:
-            args.append(stx["quant"])
-            specs.append(ispec)
-
-        def fn(qq, qa, bud, base, labels, values, nb, entries, offs, *rest):
-            j = 0
-            st = rest[j] if has_state else None
-            j += has_state
-            g = rest[j] if has_gt else None
-            j += has_gt
-            qt = rest[j] if has_quant else None
-            outs = []
-            for jj in range(nloc):            # static unroll: local shards
-                stj = (None if st is None
-                       else jax.tree.map(lambda a: a[:, jj], st))
-                qtj = (None if qt is None
-                       else jax.tree.map(lambda a: a[jj], qt))
-                outs.append(run_search_impl(
-                    cfg, qq, qa, base[jj], (labels[jj], values[jj]), nb[jj],
-                    bud, entries[jj], state=stj, gt_dist=g, quant=qtj))
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *outs)
-            # local merge tree on the global position space (shard0 keys
-            # this device's pools into the virtual concatenation of all S)
-            shard0 = jax.lax.axis_index(INDEX_AXIS) * nloc
-            off = offs[None, :, None]
-            res_g = jnp.where(stacked.res_idx >= 0,
-                              stacked.res_idx + off, -1)
-            rd, rp, ro = merge_stacked(stacked.res_dist, res_g, k,
-                                       shard0=shard0)
-            cpay = pack_payload(
-                jnp.where(stacked.cand_idx >= 0, stacked.cand_idx + off, -1),
-                stacked.cand_exp, stacked.cand_valid)
-            cd, cp, co = merge_stacked(stacked.cand_dist, cpay, m,
-                                       shard0=shard0)
-            # cross-device butterfly: after log2(dindex) rounds every index
-            # device holds the identical global pools
-            rd, rp, ro = butterfly_merge(rd, rp, ro, k, INDEX_AXIS, dindex)
-            cd, cp, co = butterfly_merge(cd, cp, co, m, INDEX_AXIS, dindex)
-            return stacked, rd, rp, cd, cp
-
-        stacked, rd, rp, cd, cp = shard_map(
-            fn, mesh=mesh, in_specs=tuple(specs),
-            out_specs=(bsspec, bspec, bspec, bspec, bspec), check_rep=False,
-        )(*args)
-        merged = merge_with_pools(stacked, rd, rp, cd, cp)
-        out = ShardedSearchState(shard=stacked, merged=merged)
+        stacked, rd, rp, cd, cp = _mesh_pools(
+            cfg, self.mesh, q, prog, sbud, self._stacked_arrays(), st_in, gt)
+        out = ShardedSearchState(
+            shard=stacked, merged=merge_with_pools(stacked, rd, rp, cd, cp))
         if pad:
             out = jax.tree.map(lambda a: a[:b], out)
         return out

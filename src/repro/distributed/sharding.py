@@ -145,15 +145,10 @@ def search_mesh_2d(n_shards: int, devices=None) -> Mesh | None:
 
 
 def _ambient_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
+    from jax._src import mesh as mesh_lib
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
 
 
 def constrain(x, logical_axes, rules: ShardingRules = DEFAULT_RULES):

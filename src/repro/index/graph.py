@@ -18,6 +18,19 @@ import dataclasses
 import numpy as np
 
 
+def random_regular_neighbors(n: int, degree: int, rng) -> np.ndarray:
+    """Self-loop-free random out-degree-`degree` neighbor lists [n, degree].
+
+    A graph of this shape at any N in well under a second: it exercises
+    the traversal, the kernels and the memory layout at scales the host
+    NN-descent builder cannot reach in time. It is not a proximity graph,
+    so recall on it says nothing about the index.
+    """
+    nb = rng.integers(0, n, size=(n, degree)).astype(np.int32)
+    rows = np.arange(n, dtype=np.int32)[:, None]
+    return np.where(nb == rows, (nb + 1) % n, nb).astype(np.int32)
+
+
 @dataclasses.dataclass
 class GraphIndex:
     neighbors: np.ndarray  # [N, R] int32, -1 padded, shard-local ids

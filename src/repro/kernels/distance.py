@@ -6,10 +6,10 @@ lanes into VMEM blocks and drives the contraction through the MXU via
 dot_general; the predicate/visited mask is fused (masked entries emit +inf
 so they never enter the queues).
 
-Block shapes: (bB lanes) × (R neighbors) × (full d). VMEM per block
-≈ bB·R·d·4 B — for bB=8, R=64, d=1024 that's 2 MB, comfortably inside the
-~16 MB v5e VMEM, with d as the MXU lane dimension (pad d to 128 upstream
-for peak utilization).
+Block shapes: (bB lanes) × (≤ BLOCK_R rows) × (full d). VMEM per block
+≈ bB·min(R, BLOCK_R)·d·4 B — 2 MiB at bB=8, BLOCK_R=512, d=128, inside the
+16 MiB of scoped VMEM a v5e kernel gets by default, with d as the MXU lane
+dimension (pad d to 128 upstream for peak utilization).
 """
 from __future__ import annotations
 
@@ -20,6 +20,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 INF = float("inf")
+
+# Distances are float32 contractions on every path. The TPU's default
+# precision rounds f32 operands to bf16 (~3 significant digits), which
+# would reorder near-tied candidates and break agreement with the oracle;
+# CPU backends compute f32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+# Rows per distance block past which `sqdist_masked` tiles the row axis.
+BLOCK_R = 512
 
 # Row-count alignment for the pre-filter scan plan's gathered distance
 # blocks. Empirically (pinned by tests/test_planner.py), XLA:CPU emits the
@@ -44,7 +53,7 @@ def sqdist_bdrd(q, x):
     x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, axis=-1)[:, None]
     xn = jnp.sum(x * x, axis=-1)
-    qx = jnp.einsum("bd,brd->br", q, x)
+    qx = jnp.einsum("bd,brd->br", q, x, precision=HIGHEST)
     return jnp.maximum(qn + xn - 2.0 * qx, 0.0)
 
 
@@ -82,7 +91,7 @@ def _sqdist_kernel(q_ref, x_ref, mask_ref, o_ref):
     qx = jax.lax.dot_general(
         q[:, None, :], x,
         dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )[:, 0, :]
     d = jnp.maximum(qn + xn - 2.0 * qx, 0.0)
     o_ref[...] = jnp.where(mask_ref[...], d, INF)
@@ -90,27 +99,34 @@ def _sqdist_kernel(q_ref, x_ref, mask_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
 def sqdist_masked(q, x, mask, *, block_b: int = 8, interpret: bool = False):
-    """q [B,d], x [B,R,d], mask [B,R] -> [B,R] f32 (+inf where masked)."""
+    """q [B,d], x [B,R,d], mask [B,R] -> [B,R] f32 (+inf where masked).
+
+    The grid tiles lanes and, past BLOCK_R rows, the R axis too, so a
+    block holds at most bB·BLOCK_R·d floats (2 MiB at bB=8, d=128)
+    whatever R is — the scan plan passes R = σ·N gathered rows.
+    """
     b, d = q.shape
     r = x.shape[1]
     bb = min(block_b, b)
+    br = min(BLOCK_R, r)
     pad = (-b) % bb
-    if pad:
+    pad_r = (-r) % br
+    if pad or pad_r:
         q = jnp.pad(q, ((0, pad), (0, 0)))
-        x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, pad), (0, 0)))
-    bp = q.shape[0]
+        x = jnp.pad(x, ((0, pad), (0, pad_r), (0, 0)))
+        mask = jnp.pad(mask, ((0, pad), (0, pad_r)))
+    bp, rp = x.shape[:2]
 
     out = pl.pallas_call(
         _sqdist_kernel,
-        grid=(bp // bb,),
+        grid=(bp // bb, rp // br),
         in_specs=[
-            pl.BlockSpec((bb, d), lambda i: (i, 0)),
-            pl.BlockSpec((bb, r, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, r), lambda i: (i, 0)),
+            pl.BlockSpec((bb, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, br, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((bb, br), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bb, r), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bp, r), jnp.float32),
+        out_specs=pl.BlockSpec((bb, br), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((bp, rp), jnp.float32),
         interpret=interpret,
     )(q, x, mask)
-    return out[:b]
+    return out[:b, :r]

@@ -58,10 +58,21 @@ from repro.filters.compile import (
     clause_counts,
     eval_program_gathered,
 )
-from repro.kernels.distance import sqdist_bdrd
-from repro.kernels.topk import bitonic_merge_sorted, merge_topm, sort_kv_f32
+from repro.kernels.distance import HIGHEST, sqdist_bdrd
+from repro.kernels.topk import (bitonic_merge_sorted, merge_topm,
+                                network_width, sort_kv_f32)
 
 INF = float("inf")
+
+
+def _all_last(x):
+    """jnp.all over the last axis as an int32 reduction (Mosaic reduces
+    no i1 vectors)."""
+    return jnp.min(x.astype(jnp.int32), axis=-1) > 0
+
+
+def _any_last(x):
+    return jnp.max(x.astype(jnp.int32), axis=-1) > 0
 
 
 def _program_valid_kernel(kinds, masks, lo, hi, vattr, neg, term, active,
@@ -80,17 +91,17 @@ def _program_valid_kernel(kinds, masks, lo, hi, vattr, neg, term, active,
     for si in range(s):
         msk = masks[:, si, :][:, None, :]                     # [bb,1,W]
         inter = jnp.bitwise_and(labels, msk)
-        c_contain = jnp.all(inter == msk, axis=-1)            # [bb,R]
-        c_equal = jnp.all(labels == msk, axis=-1)
-        c_in = jnp.any(inter != 0, axis=-1)
+        c_contain = _all_last(inter == msk)                   # [bb,R]
+        c_equal = _all_last(labels == msk)
+        c_in = _any_last(inter != 0)
         vs = values[:, :, 0]
         for ch in range(1, v_chan):                           # channel select
             vs = jnp.where(vattr[:, si][:, None] == ch, values[:, :, ch], vs)
         c_range = (vs >= lo[:, si][:, None]) & (vs <= hi[:, si][:, None])
         kk = kinds[:, si][:, None]
-        prim = jnp.where(kk == 0, c_contain,
-                         jnp.where(kk == 1, c_equal,
-                                   jnp.where(kk == 2, c_range, c_in)))
+        prim = (((kk == 0) & c_contain) | ((kk == 1) & c_equal)
+                | ((kk == 2) & c_range)
+                | ((kk != 0) & (kk != 1) & (kk != 2) & c_in))
         lit = jnp.logical_xor(prim, neg[:, si][:, None])
         act = active[:, si][:, None]
         sats.append(lit & act)
@@ -122,6 +133,8 @@ def _merge_core(d, nb, is_new, kinds, masks, lo, hi, vattr, neg, term_pack,
     # term_pack's sign bit — see fused_step packing below)
     active = term_pack >= 0
     term = jnp.maximum(term_pack, 0)
+    # flags cross the kernel boundary as int32 (Mosaic loads no i1 refs)
+    is_new, neg, tact = is_new != 0, neg != 0, tact != 0
     pvalid, sats = _program_valid_kernel(
         kinds, masks, lo, hi, vattr, neg, term, active, tact, labels, values)
     valid = pvalid & is_new
@@ -194,7 +207,7 @@ def _fused_step_kernel(q_ref, x_ref, nb_ref, new_ref, lab_ref, val_ref,
     qx = jax.lax.dot_general(
         q[:, None, :], x,
         dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )[:, 0, :]
     d = jnp.maximum(qn + xn - 2.0 * qx, 0.0)
 
@@ -248,28 +261,30 @@ def _fused_step_pq_kernel(codes_ref, lut_ref, xn_ref, qn_ref,
                           ocd_ref, ocp_ref, ord_ref, ori_ref, ov_ref,
                           occ_ref, *, m, k, wq, wr, pre, n_clause):
     """PQ ADC variant: per-query inner-product LUT rows stay resident in
-    VMEM ([bB, S·L, Kc] f32 ≈ bB·S·L·Kc·4 B — 1.5 MB at bB=8, S·L=48,
+    VMEM ([S·L, bB, Kc] f32 ≈ bB·S·L·Kc·4 B — 1.5 MB at bB=8, S·L=48,
     Kc=256) and each gathered code row costs S·L table lookups, realized
-    as one-hot × LUT-row MXU contractions per slot (statically unrolled):
+    as one-hot × LUT-row MXU contractions, one slot per trip of a rolled
+    loop (an unrolled loop over 96 slots takes Mosaic minutes to compile):
     exactly one unit weight per row, so the contraction equals the gather
     bit-for-bit while avoiding per-element dynamic indexing in the kernel.
-    The distance assembles as ‖q‖² + ‖x̂‖² − 2·Σ lookups (xn = gathered
-    per-node ‖x̂‖², qn = per-lane ‖q‖²).
+    Codes and LUT arrive slot-major ([S·L, bB, ·]) so each trip reads its
+    slot with a leading-axis index. The distance assembles as
+    ‖q‖² + ‖x̂‖² − 2·Σ lookups (xn = gathered per-node ‖x̂‖², qn = per-lane
+    ‖q‖²).
     """
-    codes = codes_ref[...]                       # [bB, R, S·L] i32
-    lut = lut_ref[...]                           # [bB, S·L, Kc] f32
-    s = codes.shape[2]
-    kc = lut.shape[2]
-    ip = jnp.zeros(codes.shape[:2], jnp.float32)
-    for si in range(s):
-        onehot = (codes[:, :, si][:, :, None]
-                  == jnp.arange(kc, dtype=jnp.int32)[None, None, :]
-                  ).astype(jnp.float32)          # [bB, R, Kc]
-        ip = ip + jax.lax.dot_general(
-            onehot, lut[:, si, :][:, :, None],
+    s, bb, r = codes_ref.shape
+    kc = lut_ref.shape[2]
+    centroid = jax.lax.broadcasted_iota(jnp.int32, (bb, kc, r), 1)
+
+    def slot(si, ip):
+        onehot = (codes_ref[si][:, None, :] == centroid).astype(jnp.float32)
+        return ip + jax.lax.dot_general(
+            lut_ref[si][:, None, :], onehot,             # [bB,1,Kc]·[bB,Kc,R]
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, :, 0]
+            precision=HIGHEST, preferred_element_type=jnp.float32,
+        )[:, 0, :]
+
+    ip = jax.lax.fori_loop(0, s, slot, jnp.zeros((bb, r), jnp.float32))
     d = jnp.maximum(qn_ref[...] + xn_ref[...] - 2.0 * ip, 0.0)
 
     _program_and_merge(
@@ -351,8 +366,8 @@ def fused_step(q, x, nb, is_new, prog, labels_g, values_g, cand_dist,
     t = prog.term_active.shape[1]
     w = labels_g.shape[2]
     v = values_g.shape[2]
-    wq = 1 << (m + r - 1).bit_length()
-    wr = 1 << (k + r - 1).bit_length()
+    wq = network_width(m + r, interpret)
+    wr = network_width(k + r, interpret)
 
     # slot activity riding in the term id's sign bit keeps the ref count
     # down (term >= 0 ⇔ active); neg packs as int32 for the same reason
@@ -374,7 +389,7 @@ def fused_step(q, x, nb, is_new, prog, labels_g, values_g, cand_dist,
     if x is not None:
         x = pad0(x)
     nb = pad0(nb, -1)
-    is_new = pad0(is_new)
+    is_new = pad0(is_new).astype(jnp.int32)
     labels_g = pad0(labels_g)
     values_g = pad0(values_g)
     kinds = pad0(prog.kinds)
@@ -382,9 +397,9 @@ def fused_step(q, x, nb, is_new, prog, labels_g, values_g, cand_dist,
     lo = pad0(prog.lo)
     hi = pad0(prog.hi)
     vattr = pad0(prog.vattr)
-    neg = pad0(prog.neg)
+    neg = pad0(prog.neg).astype(jnp.int32)
     term_pack = pad0(term_pack, -1)
-    tact = pad0(prog.term_active)
+    tact = pad0(prog.term_active).astype(jnp.int32)
     cand_dist = pad0(cand_dist, jnp.inf)
     cand_pay = pad0(cand_pay, -1)
     res_dist = pad0(res_dist, jnp.inf)
@@ -412,15 +427,17 @@ def fused_step(q, x, nb, is_new, prog, labels_g, values_g, cand_dist,
         head_specs = [row((bb, r, dq)), row((bb, r)), row((bb, dq)),
                       row((bb, 1)), row((bb, 1))]
     elif precision == "pq":
-        codes = pad0(quant.codes.astype(jnp.int32))
-        lut = pad0(quant.prep.lut)
+        # slot-major: the kernel's loop reads one slot per trip
+        codes = pad0(quant.codes.astype(jnp.int32)).transpose(2, 0, 1)
+        lut = pad0(quant.prep.lut).transpose(1, 0, 2)
         xn = pad0(quant.norms)
         qn = pad0(quant.prep.qn[:, None])
-        sp, kc = lut.shape[1], lut.shape[2]
+        sp, kc = lut.shape[0], lut.shape[2]
         head_kern = _fused_step_pq_kernel
         head_in = [codes, lut, xn, qn]
-        head_specs = [row((bb, r, sp)), row((bb, sp, kc)), row((bb, r)),
-                      row((bb, 1))]
+        head_specs = [pl.BlockSpec((sp, bb, r), lambda i: (0, i, 0)),
+                      pl.BlockSpec((sp, bb, kc), lambda i: (0, i, 0)),
+                      row((bb, r)), row((bb, 1))]
     else:
         raise ValueError(f"unknown precision {precision!r}")
 

@@ -1,6 +1,7 @@
-"""jit'd wrappers around the Pallas kernels with automatic host fallback.
+"""jit'd wrappers around the Pallas kernels, one execution per platform.
 
-On a TPU backend the kernels run compiled (Mosaic). On this CPU container:
+On a TPU backend the kernels run compiled (Mosaic). On the CPU (tests,
+`JAX_PLATFORMS=cpu`):
 
   sqdist / gbdt   execute via `interpret=True` — the kernel body itself runs
                   through the Pallas interpreter, validating semantics
@@ -25,15 +26,25 @@ from repro.kernels import topk as _topk
 from repro.kernels.topk import pack_payload, unpack_payload  # re-export
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True on the CPU (interpret mode / host twins), False on the TPU
+    (compiled kernels). Any other backend has no kernel path: an error,
+    never a silent interpret run on an accelerator."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"no kernel path for JAX backend {backend!r}: the Pallas kernels "
+        "compile for 'tpu' and run interpreted on 'cpu'")
 
 
 def batched_sqdist(q, x, mask=None):
     """q [B,d], x [B,R,d] -> [B,R] squared L2 (+inf where ~mask)."""
     if mask is None:
         mask = jnp.ones(x.shape[:2], bool)
-    return _distance.sqdist_masked(q, x, mask, interpret=_interpret())
+    return _distance.sqdist_masked(q, x, mask, interpret=interpret_mode())
 
 
 def masked_scan_dist(q, x, mask):
@@ -50,7 +61,7 @@ def masked_scan_dist(q, x, mask):
     the same bits in any batch shape. The kernel itself is still
     interpret-validated against the host path in tests/test_planner.py.
     """
-    if _interpret():
+    if interpret_mode():
         return _distance.scan_sqdist_lanes(q, x, mask)
     return _distance.sqdist_masked(q, x, mask)
 
@@ -62,7 +73,7 @@ def queue_merge(dist, payload, new_dist, new_payload):
     log-depth merge assumes the buffer is an ascending run); the TPU kernel
     happens to fully re-sort but callers must not rely on that.
     """
-    if _interpret():
+    if interpret_mode():
         return _topk.topm_merge_host(dist, payload, new_dist, new_payload)
     return _topk.topm_merge(dist, payload, new_dist, new_payload)
 
@@ -80,7 +91,7 @@ def fused_traversal_step(q, x, nb, is_new, prog, labels_g, values_g,
     the host path shares `quant.codecs.quant_dist` with the dense backend
     so compressed-mode dense/pallas parity is exact on CPU.
     """
-    if _interpret():
+    if interpret_mode():
         return _fused.fused_step_host(q, x, nb, is_new, prog, labels_g,
                                       values_g, cand_dist, cand_pay,
                                       res_dist, res_idx, pre=pre,
@@ -93,4 +104,4 @@ def fused_traversal_step(q, x, nb, is_new, prog, labels_g, values_g,
 def estimator_predict(feats, packed_model, depth):
     feat_idx, thresh, leaf, base = packed_model
     return _gbdt.gbdt_predict(feats, feat_idx, thresh, leaf, base, depth,
-                              interpret=_interpret())
+                              interpret=interpret_mode())

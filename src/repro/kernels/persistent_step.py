@@ -5,15 +5,16 @@ kernel dispatch per lockstep step, with queue / result / visited buffers and
 the gathered codes bouncing through HBM between steps. This kernel runs up
 to `steps_per_launch` steps in ONE launch:
 
-  * the candidate queue, result set, visited bitset, and every per-lane
-    counter ride the kernel's step loop as VMEM-resident carries — nothing
-    round-trips HBM until the launch boundary;
+  * the candidate queue, result set and every per-lane counter ride the
+    kernel's step loop as VMEM-resident carries, and the visited bitset is
+    updated in place in its VMEM output block — nothing round-trips HBM
+    until the launch boundary;
   * neighbor rows are gathered straight from HBM with per-row async copies
     into VMEM landing buffers, split into two streams (vector/code rows and
-    packed attribute rows) so the chunked visited-bitset pass — pure VPU
-    work that needs only the neighbor ids — overlaps both streams' DMAs,
-    the attribute wait lands just before the filter-program evaluation and
-    the row wait just before the MXU distance block;
+    packed attribute rows) so the visited-bitset pass — which needs only
+    the neighbor ids — overlaps both streams' DMAs, the attribute wait
+    lands just before the filter-program evaluation and the row wait just
+    before the MXU distance block;
   * per-lane termination (budget exhausted, queue drained, or — with
     `greedy_stop` — the paper's early-exit condition queue-head ≥
     result-tail) is evaluated *in-kernel*: a lane that trips it contributes
@@ -29,30 +30,37 @@ stop after ANY step boundary and emit a full `SearchState` that
 probe→estimate→resume, the planner's shared probe carry, and serve's lane
 surgery consume unchanged.
 
-Operand layout (built once per search call, NOT per launch):
+Operand layout (packed once per engine, `SearchEngine.persistent_operands`,
+never per launch). Every
+per-node store is one 128-lane row per node, because a one-row DMA out of
+HBM must span whole (8, 128) tiles:
 
-  rows [N, Dp]   f32 vectors | int8 codes | int32 PQ codes, row-padded to
-                 a 128-lane multiple so each row is one clean DMA.
+  rows [N, Dp]   f32 vectors | int8 codes | PQ codes, both widened to i32
+                 (int8 packs 32 rows per tile), padded to a 128-lane row.
   aux  [N, Ap]   uint32-packed per-node words:
                  [0:W) label words | [W:W+V) value channels (f32 bitcast) |
                  W+V   ‖x̂‖² ADC norm | W+V+1 reconstruction error.
                  One aux row DMA replaces three separate gathers.
+  nbrs [N, Rp]   neighbor ids, padded to a 128-lane row. Each popped
+                 node's row lands twice: in VMEM for the vector work and in
+                 SMEM for the scalar row addresses and bitset slots.
 
-VMEM per block (bb lanes), on top of the single-step budget:
-visited bitset bb·ceil(N/32)·4 B (~12.5 KB/lane at N=100k), landing
-buffers bb·R·(Dp + Ap)·4 B, plus the loop-carried queue/result buffers the
-single-step kernel already held — comfortably inside the ~2.3 MB/block
-budget of docs/ARCHITECTURE.md for bb=8.
+The visited bitset crosses the kernel boundary as [⌈nw/128⌉, B, 128] int32
+(`_bitset_rows`): word w of lane l sits at row w // 128, so a test is one
+dynamic-row load of an (8, 128) tile. VMEM per block (bb lanes) on top of
+the single-step budget: the bitset's input and output blocks, each
+bb·⌈N/4096⌉·512 B and double-buffered (1 MiB each at N = 2^20, bb = 8),
+plus the landing buffers bb·R·(Dp + Ap + Rp)·4 B.
 
 The kernel covers `mode="post"` (1-hop frontier, the serving hot path);
 pre/widen frontiers (1-hop ∪ strided 2-hop with intra-step dedup) keep the
-host multi-step path in core/search.py, which is also the non-TPU
-(XLA:CPU) execution of the `pallas_persistent` backend. A further step of
-DMA pipelining — speculatively prefetching the *next* pop's rows during
-the current merge, with an eviction guard when the merge changes the queue
-head — is documented in docs/ARCHITECTURE.md as TPU-measurement future
-work; the pop→gather dependency makes it a semantics-preserving gamble
-rather than a straight rotation.
+host multi-step path in core/search.py, which is also the CPU execution of
+the `pallas_persistent` backend. A further step of DMA pipelining —
+speculatively prefetching the *next* pop's rows during the current merge,
+with an eviction guard when the merge changes the queue head — is
+documented in docs/ARCHITECTURE.md as TPU-measurement future work; the
+pop→gather dependency makes it a semantics-preserving gamble rather than
+a straight rotation.
 """
 from __future__ import annotations
 
@@ -64,8 +72,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.filters.compile import CLAUSE_FEATURE_SLOTS
+from repro.kernels.distance import HIGHEST
 from repro.kernels.fused_step import _merge_core
-from repro.kernels.topk import pack_payload, unpack_payload
+from repro.kernels.topk import (LANES as _LANES, network_width, pack_payload,
+                                unpack_payload)
 
 INF = float("inf")
 
@@ -86,12 +96,16 @@ def _pad_cols(a, width, fill=0):
 
 @functools.partial(jax.jit, static_argnames=("precision",))
 def build_persistent_operands(precision, base_vectors, label_attrs,
-                              value_attrs, quant):
-    """Pack the per-node HBM operands (rows, aux) for the persistent kernel.
+                              value_attrs, neighbors, quant):
+    """Pack the per-node HBM operands (rows, aux, nbrs) for the persistent
+    kernel.
 
-    Called once per search call — per-launch packing would cost O(N·A)
-    every launch and erase the dispatch-amortization win. Returns
-    (rows [N, Dp], aux [N, Ap] u32); see the module docstring for layout.
+    The engine packs them once and keeps them (`SearchEngine
+    .persistent_operands`): per-call packing made probe and resume each
+    pay O(N·128) words of transient HBM, per-launch packing would also
+    erase the dispatch-amortization win. Returns
+    (rows [N, Dp], aux [N, Ap] u32, nbrs [N, Rp] i32); see the module
+    docstring for layout.
     """
     from repro.quant.codecs import pad_rows_for_dma
 
@@ -101,7 +115,9 @@ def build_persistent_operands(precision, base_vectors, label_attrs,
         xn = jnp.zeros((n,), jnp.float32)
         err = jnp.zeros((n,), jnp.float32)
     elif precision == "int8":
-        rows = pad_rows_for_dma(quant.codes)                   # [N, d] i8
+        # widened to i32 rows: a one-row DMA out of HBM must span whole
+        # (8, 128) tiles, and int8 packs 32 rows into each
+        rows = pad_rows_for_dma(quant.codes.astype(jnp.int32))
         xn, err = quant.norms, quant.err
     elif precision == "pq":
         # uint8 store widened to i32 once: the in-kernel one-hot LUT
@@ -121,16 +137,23 @@ def build_persistent_operands(precision, base_vectors, label_attrs,
         bc(xn)[:, None],
         bc(err)[:, None],
     ], axis=1)
-    return rows, pad_rows_for_dma(aux)
+    return rows, pad_rows_for_dma(aux), pad_rows_for_dma(neighbors)
 
 
-def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
-                       n_head, steps, greedy, has_gt, precision, n_clause):
+def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, nrow, n_head,
+                       steps, greedy, has_gt, precision, n_clause):
     """One launch: up to `steps` lockstep traversal steps, state in VMEM.
 
     Ref order: rem (SMEM) | nbrs, rows, aux (HBM) | head inputs (n_head) |
     8 program leaves | budgets | [gt] | cd, cp, rd, ri, vis, ctr, ncl, qerr
-    | 8 outputs | nbid, vbuf, abuf + 3 DMA semaphore arrays (scratch).
+    | 8 outputs | nbid, nbs, seen_s, vbuf, abuf + 3 DMA semaphore arrays
+    (scratch).
+
+    Per-lane quantities are [bb, 1] columns and flags ride as int32:
+    Mosaic lowers neither 1-D vectors nor i1 loads, reductions or scalar
+    extracts reliably. The visited bitset is updated in place in the
+    output block, laid out [nrow, bb, 128] so that one word test is one
+    dynamic-row vreg load.
     """
     it = iter(refs)
     rem_ref = next(it)
@@ -144,19 +167,20 @@ def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
      qerr_ref) = (next(it) for _ in range(8))
     (ocd_ref, ocp_ref, ord_ref, ori_ref, ovis_ref, octr_ref, oncl_ref,
      oqerr_ref) = (next(it) for _ in range(8))
-    nbid, vbuf, abuf, nsem, vsem, asem = (next(it) for _ in range(6))
+    nbid, nbs, seen_s, vbuf, abuf, nsem, vsem, asem = (
+        next(it) for _ in range(8))
 
     # ---- loop-invariant VMEM loads (once per launch, not per step) ----
     kinds, masks = kinds_ref[...], masks_ref[...]
     lo, hi = lo_ref[...], hi_ref[...]
     vattr, neg = vattr_ref[...], neg_ref[...]
     term_pack, tact = term_ref[...], tact_ref[...]
-    budgets = bud_ref[...][:, 0]
+    budgets = bud_ref[...]                                     # [bb, 1]
     gt = gt_ref[...] if has_gt else None
     rem = rem_ref[0]
     if precision == "float32":
         q = heads[0][...].astype(jnp.float32)                  # [bb, Dp]
-        qn_head = jnp.sum(q * q, axis=-1)[:, None]
+        qn_head = jnp.sum(q * q, axis=-1, keepdims=True)
     elif precision == "int8":
         qq = heads[0][...]                                     # [bb, Dp] i8
         sq, qn_head = heads[1][...], heads[2][...]             # [bb, 1] f32
@@ -166,106 +190,135 @@ def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
         sl = lut.shape[1]
 
     ctr0 = ctr_ref[...]
+    ovis_ref[...] = vis_ref[...]     # the bitset is updated in place below
     f32 = functools.partial(jax.lax.bitcast_convert_type,
                             new_dtype=jnp.float32)
+    col_m = jax.lax.broadcasted_iota(jnp.int32, (bb, m), 1)
+    row_1 = jax.lax.broadcasted_iota(jnp.int32, (bb, 1), 0)
+    row_r = jax.lax.broadcasted_iota(jnp.int32, (bb, r), 0)
+    col_r = jax.lax.broadcasted_iota(jnp.int32, (bb, r), 1)
+    row_v = jax.lax.broadcasted_iota(jnp.int32, (bb, _LANES), 0)
+    col_v = jax.lax.broadcasted_iota(jnp.int32, (bb, _LANES), 1)
+
+    def lane_scalar(col, l):
+        """Element l of an int32 [bb, 1] column, as a scalar."""
+        return jnp.sum(jnp.where(row_1 == l, col, 0))
+
+    def bitset_slot(l, ri):
+        """(row, in-tile mask, bit) of lane l's neighbor ri in the bitset."""
+        j = jnp.maximum(nbs[l, ri], 0)
+        word = j >> 5
+        row = jnp.minimum(word >> 7, nrow - 1)
+        at = (row_v == l) & (col_v == (word & (_LANES - 1)))
+        return row, at, j & 31
 
     def body(carry):
-        (s, cd, cp, rdv, riv, vis, cnt, nin, nvv, nclv, npv, qerr, hops,
+        (s, cd, cp, rdv, riv, cnt, nin, nvv, nclv, npv, qerr, hops,
          prev_act, conv, rfull) = carry
 
         # ---- pop best unexpanded candidate per lane ----
         idx, exp, vbit = unpack_payload(cp)
         unexp = (~exp) & (idx >= 0)
         pop_key = jnp.where(unexp, cd, INF)
-        p = jnp.argmin(pop_key, axis=1)                        # [bb]
-        sel = (jax.lax.broadcasted_iota(jnp.int32, (bb, m), 1)
-               == p[:, None])
-        best_d = jnp.min(pop_key, axis=1)
-        has_cand = jnp.isfinite(best_d)
-        u = jnp.sum(jnp.where(sel, idx, 0), axis=1)
-        u_valid = jnp.any(sel & vbit, axis=1)
+        best_d = jnp.min(pop_key, axis=1, keepdims=True)       # [bb, 1]
+        # first minimum, as argmin picks it
+        p = jnp.min(jnp.where(pop_key == best_d, col_m, m), axis=1,
+                    keepdims=True)
+        sel = col_m == p
+        has_cand = best_d < INF
+        u = jnp.sum(jnp.where(sel, idx, 0), axis=1, keepdims=True)
+        u_valid = jnp.max(jnp.where(sel & vbit, 1, 0), axis=1,
+                          keepdims=True) > 0
 
         # ---- in-kernel per-lane termination (the adaptive early exit) ----
-        act = prev_act & has_cand & (cnt < budgets)
+        act = (prev_act != 0) & has_cand & (cnt < budgets)
         if greedy:
-            worst_res = rdv[:, -1]
-            act = act & ~(jnp.isfinite(worst_res) & (best_d > worst_res))
+            worst_res = rdv[:, k - 1:k]
+            act = act & ~((worst_res < INF) & (best_d > worst_res))
+        act_i = act.astype(jnp.int32)
+        act_s = [lane_scalar(act_i, l) for l in range(bb)]
 
         # mark the popped slot expanded (lane-masked, as on the host)
-        cp_pop = jnp.where(sel & act[:, None], cp | (1 << 29), cp)
+        cp_pop = jnp.where(sel & act, cp | (1 << 29), cp)
 
         # ---- gather frontier neighbor ids (1-hop row DMA per lane) ----
+        # one copy lands in VMEM for the vector work, one in SMEM for the
+        # scalar row addresses and bitset slots
         u_safe = jnp.maximum(u, 0)
+
+        def nbr_copies(l):
+            ul = lane_scalar(u_safe, l)
+            return (pltpu.make_async_copy(nbrs_hbm.at[ul], nbid.at[l],
+                                          nsem.at[0, l]),
+                    pltpu.make_async_copy(nbrs_hbm.at[ul], nbs.at[l],
+                                          nsem.at[1, l]))
+
         for l in range(bb):
-            @pl.when(act[l])
+            @pl.when(act_s[l] != 0)
             def _(l=l):
-                pltpu.make_async_copy(
-                    nbrs_hbm.at[u_safe[l]], nbid.at[l], nsem.at[l]).start()
+                for c in nbr_copies(l):
+                    c.start()
         for l in range(bb):
-            @pl.when(act[l])
+            @pl.when(act_s[l] != 0)
             def _(l=l):
-                pltpu.make_async_copy(
-                    nbrs_hbm.at[u_safe[l]], nbid.at[l], nsem.at[l]).wait()
-        nb = jnp.where(act[:, None], nbid[...], -1)
-        nb_safe = jnp.maximum(nb, 0)
+                for c in nbr_copies(l):
+                    c.wait()
+        nb = jnp.where(act, nbid[...][:, :r], -1)
 
         # ---- launch both gather streams (vector/code rows + aux rows) ----
         # Finished lanes issue nothing: their DMA slots stay idle and the
         # stale landing buffers are masked out of every consumer below.
+        # One DMA semaphore per lane and stream (semaphore memory holds a
+        # few hundred): each wait below retires one row's bytes.
+        def row_copy(src, dst, sem, l, ri_):
+            j = jnp.maximum(nbs[l, ri_], 0)
+            return pltpu.make_async_copy(src.at[j], dst.at[l, ri_], sem.at[l])
+
         for l in range(bb):
-            @pl.when(act[l])
+            @pl.when(act_s[l] != 0)
             def _(l=l):
                 for ri_ in range(r):
-                    j = nb_safe[l, ri_]
-                    pltpu.make_async_copy(
-                        rows_hbm.at[j], vbuf.at[l, ri_],
-                        vsem.at[l, ri_]).start()
-                    pltpu.make_async_copy(
-                        aux_hbm.at[j], abuf.at[l, ri_],
-                        asem.at[l, ri_]).start()
+                    row_copy(rows_hbm, vbuf, vsem, l, ri_).start()
+                    row_copy(aux_hbm, abuf, asem, l, ri_).start()
 
         # ---- visited test-before-set, overlapping the in-flight DMAs ----
-        # Chunked over the word axis: per chunk, membership is an equality
-        # one-hot against the chunk's word ids — no dynamic gather/scatter,
-        # only elementwise + reductions (Mosaic-friendly). Testing against
-        # the PRE-step words per chunk preserves the host's duplicate-id
-        # semantics exactly (both copies of a repeated id count as new).
-        word_idx = nb_safe >> 5
-        bit = jnp.uint32(1) << (nb_safe & 31).astype(jnp.uint32)
-        nb_ok = (nb >= 0) & act[:, None]
-        seen = jnp.zeros((bb, r), bool)
-        new_chunks = []
-        for c in range(n_chunks):
-            ids = (jax.lax.broadcasted_iota(jnp.int32, (bb, r, cw), 2)
-                   + c * cw)
-            match = word_idx[:, :, None] == ids
-            vw = vis[:, c * cw:(c + 1) * cw]                   # [bb, cw]
-            hit = match & ((vw[:, None, :] & bit[:, :, None]) != 0)
-            seen_c = jnp.any(hit, axis=2)
-            seen = seen | seen_c
-            new_c = nb_ok & (~seen_c) & jnp.any(match, axis=2)
-            bits = jnp.where(match & new_c[:, :, None], bit[:, :, None],
-                             jnp.uint32(0))
-            # integer ADD, not OR: the host marks via .add(mode="drop"), so
-            # a neighbor id repeated within one row carries into the next
-            # bit — bit-compatibility means reproducing that carry exactly.
-            add = bits[:, 0, :]
-            for ri_ in range(1, r):
-                add = add + bits[:, ri_, :]
-            new_chunks.append(vw + add)
-        vis_new = (jnp.concatenate(new_chunks, axis=1)
-                   if n_chunks > 1 else new_chunks[0])
-        is_new = nb_ok & (~seen)
+        # Every test reads the PRE-step words before any bit is set, which
+        # keeps the host's duplicate-id semantics (both copies of an id
+        # repeated within one row count as new). The set is an integer
+        # ADD, not an OR: the host marks via .add(mode="drop"), so a
+        # repeated id carries into the next bit, and so does this.
+        seen = jnp.zeros((bb, r), jnp.int32)
+        for l in range(bb):
+            def test(ri_, seen, l=l):
+                row, at, sh = bitset_slot(l, ri_)
+                word = jnp.sum(jnp.where(at, ovis_ref[row], 0))
+                hit = (word >> sh) & 1
+                seen_s[l, ri_] = hit
+                return jnp.where((row_r == l) & (col_r == ri_), hit, seen)
+
+            seen = jax.lax.cond(act_s[l] != 0,
+                                lambda sn, l=l, f=test: jax.lax.fori_loop(
+                                    0, r, f, sn),
+                                lambda sn: sn, seen)
+        is_new = (nb >= 0) & (seen == 0)      # nb < 0 on finished lanes
+
+        for l in range(bb):
+            @pl.when(act_s[l] != 0)
+            def _(l=l):
+                @pl.loop(0, r)
+                def _(ri_):
+                    @pl.when((nbs[l, ri_] >= 0) & (seen_s[l, ri_] == 0))
+                    def _():
+                        row, at, sh = bitset_slot(l, ri_)
+                        ovis_ref[row] = ovis_ref[row] + jnp.where(
+                            at, jnp.left_shift(jnp.int32(1), sh), 0)
 
         # ---- attribute stream lands: unpack the packed aux words ----
         for l in range(bb):
-            @pl.when(act[l])
+            @pl.when(act_s[l] != 0)
             def _(l=l):
                 for ri_ in range(r):
-                    j = nb_safe[l, ri_]
-                    pltpu.make_async_copy(
-                        aux_hbm.at[j], abuf.at[l, ri_],
-                        asem.at[l, ri_]).wait()
+                    row_copy(aux_hbm, abuf, asem, l, ri_).wait()
         auxv = abuf[...]
         labels_g = auxv[:, :, :w]
         values_g = f32(auxv[:, :, w:w + v])
@@ -275,23 +328,21 @@ def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
         # ---- row stream lands: distance block (same math per codec as
         # the single-step kernels in fused_step.py) ----
         for l in range(bb):
-            @pl.when(act[l])
+            @pl.when(act_s[l] != 0)
             def _(l=l):
                 for ri_ in range(r):
-                    j = nb_safe[l, ri_]
-                    pltpu.make_async_copy(
-                        rows_hbm.at[j], vbuf.at[l, ri_],
-                        vsem.at[l, ri_]).wait()
+                    row_copy(rows_hbm, vbuf, vsem, l, ri_).wait()
         if precision == "float32":
             x = vbuf[...].astype(jnp.float32)                  # [bb, r, Dp]
             xn = jnp.sum(x * x, axis=-1)
             qx = jax.lax.dot_general(
                 q[:, None, :], x,
                 dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                precision=HIGHEST,
                 preferred_element_type=jnp.float32)[:, 0, :]
             d = jnp.maximum(qn_head + xn - 2.0 * qx, 0.0)
         elif precision == "int8":
-            codes = vbuf[...]                                  # [bb, r, Dp] i8
+            codes = vbuf[...].astype(jnp.int8)                 # [bb, r, Dp]
             dot = jax.lax.dot_general(
                 qq[:, None, :], codes,
                 dimension_numbers=(((2,), (2,)), ((0,), (0,))),
@@ -309,6 +360,7 @@ def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
                 ip = ip + jax.lax.dot_general(
                     onehot, lut[:, si, :][:, :, None],
                     dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                    precision=HIGHEST,
                     preferred_element_type=jnp.float32)[:, :, 0]
             d = jnp.maximum(qn_head + xn_aux - 2.0 * ip, 0.0)
 
@@ -319,55 +371,57 @@ def _persistent_kernel(*refs, bb, m, k, r, w, v, wq, wr, cw, n_chunks,
             m=m, k=k, wq=wq, wr=wr, pre=False, n_clause=n_clause)
 
         # ---- counters, lane-masked exactly as core.step.make_step ----
-        ndc_add = is_new.sum(axis=1).astype(jnp.int32)         # post mode
-        valid_add = valid.sum(axis=1).astype(jnp.int32)
-        err_add = jnp.where(is_new, err_g, 0.0).sum(axis=1)
+        def rsum(x):
+            return jnp.sum(x, axis=1, keepdims=True)
+
+        ndc_add = rsum(is_new.astype(jnp.int32))               # post mode
+        valid_add = rsum(valid.astype(jnp.int32))
+        err_add = rsum(jnp.where(is_new, err_g, 0.0))
         cnt_n = cnt + jnp.where(act, ndc_add, 0)
         nin_n = nin + jnp.where(act, ndc_add, 0)
         nvv_n = nvv + jnp.where(act, valid_add, 0)
-        nclv_n = nclv + jnp.where(act[:, None], occ, 0)
+        nclv_n = nclv + jnp.where(act, occ, 0)
         npv_n = npv + jnp.where(act & u_valid, 1, 0)
         qerr_n = qerr + jnp.where(act, err_add, 0.0)
         hops_n = hops + jnp.where(act, 1, 0)
 
         if has_gt:
-            covered = jnp.all(ordd <= gt + 1e-6, axis=1)
+            covered = jnp.min(jnp.where(ordd <= gt + 1e-6, 1, 0), axis=1,
+                              keepdims=True) > 0
             conv_n = jnp.where((conv < 0) & covered, cnt_n, conv)
         else:
             conv_n = conv
-        now_full = jnp.isfinite(ordd[:, -1]) & act
+        now_full = (ordd[:, k - 1:k] < INF) & act
         rfull_n = jnp.where((rfull < 0) & now_full, cnt_n, rfull)
 
-        am = act[:, None]
         return (s + 1,
-                jnp.where(am, ocd, cd), jnp.where(am, ocp, cp_pop),
-                jnp.where(am, ordd, rdv), jnp.where(am, ori, riv),
-                jnp.where(am, vis_new, vis),
+                jnp.where(act, ocd, cd), jnp.where(act, ocp, cp_pop),
+                jnp.where(act, ordd, rdv), jnp.where(act, ori, riv),
                 cnt_n, nin_n, nvv_n, nclv_n, npv_n, qerr_n, hops_n,
-                act, conv_n, rfull_n)
+                act_i, conv_n, rfull_n)
 
     def cond(carry):
         s = carry[0]
-        prev_act = carry[13]
-        return (s < steps) & (s < rem) & jnp.any(prev_act)
+        prev_act = carry[12]
+        return (s < steps) & (s < rem) & (jnp.max(prev_act) > 0)
+
+    def col(c):
+        return ctr0[:, c:c + 1]
 
     init = (jnp.int32(0), cd_ref[...], cp_ref[...], rd_ref[...], ri_ref[...],
-            vis_ref[...], ctr0[:, 0], ctr0[:, 1], ctr0[:, 2], ncl_ref[...],
-            ctr0[:, 3], qerr_ref[...][:, 0], ctr0[:, 4],
-            ctr0[:, 7].astype(bool), ctr0[:, 5], ctr0[:, 6])
-    (_, cd, cp, rdv, riv, vis, cnt, nin, nvv, nclv, npv, qerr, hops, act,
+            col(0), col(1), col(2), ncl_ref[...], col(3), qerr_ref[...],
+            col(4), col(7), col(5), col(6))
+    (_, cd, cp, rdv, riv, cnt, nin, nvv, nclv, npv, qerr, hops, act,
      conv, rfull) = jax.lax.while_loop(cond, body, init)
 
     ocd_ref[...] = cd
     ocp_ref[...] = cp
     ord_ref[...] = rdv
     ori_ref[...] = riv
-    ovis_ref[...] = vis
-    octr_ref[...] = jnp.stack(
-        [cnt, nin, nvv, npv, hops, conv, rfull, act.astype(jnp.int32)],
-        axis=1)
+    for c, x in enumerate((cnt, nin, nvv, npv, hops, conv, rfull, act)):
+        octr_ref[:, c:c + 1] = x
     oncl_ref[...] = nclv
-    oqerr_ref[...] = qerr[:, None]
+    oqerr_ref[...] = qerr
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "steps", "n_values",
@@ -379,9 +433,10 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
                           interpret: bool = False, block_b: int = 8):
     """Run up to `steps` lockstep traversal steps in one kernel launch.
 
-    rows/aux are the per-node HBM operands from `build_persistent_operands`
-    (packed once per search call); `rem` is a traced scalar bound on how
-    many steps this launch may still take (cfg.max_steps bookkeeping), and
+    rows/aux/neighbors are the per-node HBM operands from
+    `build_persistent_operands` (packed once per engine); `rem` is a
+    traced scalar bound on how many steps this launch may still take
+    (cfg.max_steps bookkeeping), and
     the kernel additionally stops the moment every lane terminates.
     Returns a full `SearchState`, bit-compatible with `steps` iterations of
     the single-step path (post mode).
@@ -396,11 +451,13 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
     dp = rows.shape[1]
     ap = aux.shape[1]
     v = n_values  # aux cols [w, w+v) — ap is DMA-padded, not layout-tight
-    wq = 1 << (m + r - 1).bit_length()
-    wr = 1 << (k + r - 1).bit_length()
-    cw = min(128, 1 << (nw - 1).bit_length())
-    n_chunks = -(-nw // cw)
-    nwp = n_chunks * cw
+    wq = network_width(m + r, interpret)
+    wr = network_width(k + r, interpret)
+    nrow = -(-nw // _LANES)      # bitset rows of 128 words per lane
+    rp = neighbors.shape[1]      # one lane-aligned row DMA per node
+    if rp % _LANES:
+        raise ValueError(f"neighbor rows are {rp} wide: pass the "
+                         "lane-padded nbrs of build_persistent_operands")
     term_pack = jnp.where(prog.active, prog.term, -1).astype(jnp.int32)
 
     # The per-lane DMA issue is statically unrolled over the block's lanes,
@@ -438,8 +495,8 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
 
     inputs = head_in + [
         pad0(prog.kinds), pad0(prog.masks), pad0(prog.lo), pad0(prog.hi),
-        pad0(prog.vattr), pad0(prog.neg), pad0(term_pack, -1),
-        pad0(prog.term_active),
+        pad0(prog.vattr), pad0(prog.neg).astype(jnp.int32),
+        pad0(term_pack, -1), pad0(prog.term_active).astype(jnp.int32),
         pad0(jnp.asarray(budgets, jnp.int32)[:, None]),
     ]
     in_specs = head_specs + [
@@ -453,30 +510,31 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
     inputs += [
         pad0(state.cand_dist.astype(jnp.float32), jnp.inf), pad0(cp, -1),
         pad0(state.res_dist.astype(jnp.float32), jnp.inf),
-        pad0(state.res_idx, -1),
-        _pad_cols(pad0(state.visited), nwp), pad0(ctr),
+        pad0(state.res_idx, -1), _bitset_rows(pad0(state.visited), nrow),
+        pad0(ctr),
         pad0(state.n_clause_valid), pad0(state.q_err_sum[:, None]),
     ]
+    vis_spec = pl.BlockSpec((nrow, bb, _LANES), lambda i: (0, i, 0))
     in_specs += [
         _row((bb, m)), _row((bb, m)), _row((bb, k)), _row((bb, k)),
-        _row((bb, nwp)), _row((bb, 8)), _row((bb, CLAUSE_FEATURE_SLOTS)),
+        vis_spec, _row((bb, 8)), _row((bb, CLAUSE_FEATURE_SLOTS)),
         _row((bb, 1)),
     ]
     bp = b + pad
 
     kern = functools.partial(
         _persistent_kernel, bb=bb, m=m, k=k, r=r, w=w, v=v, wq=wq, wr=wr,
-        cw=cw, n_chunks=n_chunks, n_head=len(head_in), steps=steps,
+        nrow=nrow, n_head=len(head_in), steps=steps,
         greedy=cfg.greedy_stop, has_gt=has_gt, precision=precision,
         n_clause=CLAUSE_FEATURE_SLOTS)
     ocd, ocp, ordd, ori, ovis, octr, oncl, oqerr = pl.pallas_call(
         kern,
         grid=(bp // bb,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [pl.BlockSpec(memory_space=pltpu.ANY)] * 3 + in_specs,
+        + [pl.BlockSpec(memory_space=pl.ANY)] * 3 + in_specs,
         out_specs=[
             _row((bb, m)), _row((bb, m)), _row((bb, k)), _row((bb, k)),
-            _row((bb, nwp)), _row((bb, 8)),
+            vis_spec, _row((bb, 8)),
             _row((bb, CLAUSE_FEATURE_SLOTS)), _row((bb, 1)),
         ],
         out_shape=[
@@ -484,18 +542,20 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
             jax.ShapeDtypeStruct((bp, m), jnp.int32),
             jax.ShapeDtypeStruct((bp, k), jnp.float32),
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
-            jax.ShapeDtypeStruct((bp, nwp), jnp.uint32),
+            jax.ShapeDtypeStruct((nrow, bp, _LANES), jnp.int32),
             jax.ShapeDtypeStruct((bp, 8), jnp.int32),
             jax.ShapeDtypeStruct((bp, CLAUSE_FEATURE_SLOTS), jnp.int32),
             jax.ShapeDtypeStruct((bp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bb, r), jnp.int32),
+            pltpu.VMEM((bb, rp), jnp.int32),
+            pltpu.SMEM((bb, rp), jnp.int32),
+            pltpu.SMEM((bb, r), jnp.int32),
             pltpu.VMEM((bb, r, dp), rows.dtype),
             pltpu.VMEM((bb, r, ap), jnp.uint32),
+            pltpu.SemaphoreType.DMA((2, bb)),
             pltpu.SemaphoreType.DMA((bb,)),
-            pltpu.SemaphoreType.DMA((bb, r)),
-            pltpu.SemaphoreType.DMA((bb, r)),
+            pltpu.SemaphoreType.DMA((bb,)),
         ],
         interpret=interpret,
     )(jnp.asarray(rem, jnp.int32).reshape(1), neighbors, rows, aux, *inputs)
@@ -505,12 +565,28 @@ def persistent_multi_step(cfg, queries, prog, rows, aux, neighbors, budgets,
 
     return SearchState(
         cand_dist=ocd[:b], cand_idx=idx, cand_exp=exp, cand_valid=vbit,
-        res_dist=ordd[:b], res_idx=ori[:b], visited=ovis[:b, :nw],
+        res_dist=ordd[:b], res_idx=ori[:b],
+        visited=_bitset_words(ovis)[:b, :nw],
         cnt=octr[:b, 0], n_inspected=octr[:b, 1],
         n_valid_visited=octr[:b, 2], n_clause_valid=oncl[:b],
         n_pop_valid=octr[:b, 3], q_err_sum=oqerr[:b, 0], hops=octr[:b, 4],
         active=octr[:b, 7].astype(bool), d_start=state.d_start,
         conv_cnt=octr[:b, 5], res_full_cnt=octr[:b, 6])
+
+
+def _bitset_rows(visited, nrow):
+    """[B, nw] u32 bitset -> the kernel's [nrow, B, 128] int32 layout."""
+    b = visited.shape[0]
+    words = _pad_cols(visited, nrow * _LANES)
+    words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    return words.reshape(b, nrow, _LANES).transpose(1, 0, 2)
+
+
+def _bitset_words(rows):
+    """Inverse of `_bitset_rows` (word-padded to nrow·128)."""
+    nrow, b, _ = rows.shape
+    words = rows.transpose(1, 0, 2).reshape(b, nrow * _LANES)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _row(shape):

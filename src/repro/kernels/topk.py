@@ -8,6 +8,8 @@ flags) ride through the same selects.
 
 Width = next_pow2(M+R); the network has log²(width) stages of [bB, width]
 element-wise ops — for M=512, R=64 that's 55 stages on a 1024-wide block.
+Compiled for the TPU the width is at least one 128-lane vreg row
+(`network_width`): lane rotations are only lowered on whole vregs.
 """
 from __future__ import annotations
 
@@ -16,10 +18,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import _bitonic_stages
 
 INF = float("inf")
+
+# Narrowest network the compiled kernels run: one vreg row of lanes.
+LANES = 128
+
+
+def network_width(n: int, interpret: bool) -> int:
+    """Sorting-network width for n live entries: next_pow2(n), raised to a
+    full vreg row when compiled (interpret mode keeps the narrow network —
+    XLA:CPU compile time grows exponentially with its stage count)."""
+    w = 1 << (n - 1).bit_length()
+    return w if interpret else max(w, LANES)
 
 
 def bitonic_topm(keys, vals, m):
@@ -29,20 +43,29 @@ def bitonic_topm(keys, vals, m):
     Shared by the standalone queue-merge kernel below and the fused
     traversal-step kernel (kernels.fused_step), which runs it twice —
     once at queue width, once at result width — inside one VMEM pass.
+
+    The compare-exchange partner of lane i is i ^ j: i + j where bit j of
+    i is clear, i - j where it is set. Two lane rotations and a select
+    give it, so the network needs no gather (which Mosaic cannot lower).
     """
-    width = keys.shape[1]
-    idx = jnp.arange(width)
+    b, width = keys.shape
+    idx = jax.lax.broadcasted_iota(jnp.int32, (b, width), 1)
     for j, k in _bitonic_stages(width):
-        partner = idx ^ j
+        first = (idx & j) == 0                   # i < partner
         asc = (idx & k) == 0
-        k_part = keys[:, partner]
-        v_part = vals[:, partner]
-        first = idx < partner
-        keep_self = jnp.where(
-            first,
-            jnp.where(asc, keys <= k_part, keys >= k_part),
-            jnp.where(asc, k_part <= keys, k_part >= keys),
-        )
+
+        def partner(x, first=first, j=j):
+            # roll(x, s)[i] == x[i - s]: shift width - j reads x[i + j]
+            return jnp.where(first, pltpu.roll(x, width - j, 1),
+                             pltpu.roll(x, j, 1))
+
+        k_part = partner(keys)
+        v_part = partner(vals)
+        # the lower lane of an ascending pair keeps the smaller key, and so
+        # on; boolean algebra, not a select of booleans (Mosaic lowers no
+        # i1 select)
+        up = ~(first ^ asc)
+        keep_self = (up & (keys <= k_part)) | (~up & (keys >= k_part))
         keys = jnp.where(keep_self, keys, k_part)
         vals = jnp.where(keep_self, vals, v_part)
     return keys[:, :m], vals[:, :m]
@@ -53,11 +76,12 @@ def merge_topm(dist, pay, new_dist, new_pay, m, width):
     keep the best m via the bitonic network (width = next_pow2(M+R))."""
     b = dist.shape[0]
     pad = width - dist.shape[1] - new_dist.shape[1]
-    keys = jnp.concatenate(
-        [dist, new_dist, jnp.full((b, pad), INF)], axis=1)
-    vals = jnp.concatenate(
-        [pay, new_pay, jnp.full((b, pad), -1, jnp.int32)], axis=1)
-    return bitonic_topm(keys, vals, m)
+    keys, vals = [dist, new_dist], [pay, new_pay]
+    if pad:
+        keys.append(jnp.full((b, pad), INF, jnp.float32))
+        vals.append(jnp.full((b, pad), -1, jnp.int32))
+    return bitonic_topm(jnp.concatenate(keys, axis=1),
+                        jnp.concatenate(vals, axis=1), m)
 
 
 def _merge_kernel(dist_ref, pay_ref, nd_ref, np_ref, od_ref, op_ref, *, m, width):
@@ -71,7 +95,7 @@ def topm_merge(dist, payload, new_dist, new_payload, *, block_b: int = 8,
     """Merge sorted [B,M] + [B,R] -> sorted best-M (dist, payload)."""
     b, m = dist.shape
     r = new_dist.shape[1]
-    width = 1 << (m + r - 1).bit_length()
+    width = network_width(m + r, interpret)
     bb = min(block_b, b)
     pad = (-b) % bb
     if pad:
@@ -201,6 +225,6 @@ def pack_payload(idx, expanded, valid):
 def unpack_payload(p):
     neg = p < 0
     idx = jnp.where(neg, -1, p & ((1 << 29) - 1))
-    expanded = jnp.where(neg, False, (p >> 29) & 1 != 0)
-    valid = jnp.where(neg, False, (p >> 30) & 1 != 0)
+    expanded = ~neg & ((p >> 29) & 1 != 0)
+    valid = ~neg & ((p >> 30) & 1 != 0)
     return idx, expanded, valid

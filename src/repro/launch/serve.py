@@ -26,7 +26,8 @@ import numpy as np
 
 def build_world(corpus: int, train_queries: int, queue_size: int, k: int,
                 probe: int, backend: str | None, seed: int = 0,
-                precision: str = "float32", n_shards: int = 1):
+                precision: str = "float32", n_shards: int = 1,
+                ds=None, graph=None):
     """Index + graph + engine + a single estimator trained on a *mixed*
     contain/range workload (features are predicate-agnostic, so one GBDT
     serves both request kinds). `precision` deploys the engine with a
@@ -35,7 +36,10 @@ def build_world(corpus: int, train_queries: int, queue_size: int, k: int,
     the scheduler reranks every finished lane with exact float32.
     `n_shards > 1` deploys an index-axis-sharded engine (core.sharded)
     with one independent graph per corpus slice; the estimator is trained
-    on that same sharded engine, so it models the ⌈W/S⌉-split cost."""
+    on that same sharded engine, so it models the ⌈W/S⌉-split cost.
+    `ds` / `graph` deploy a given dataset (e.g. a `data.make_preset`) and
+    an already-built graph instead of building them here; `corpus` is then
+    ignored."""
     import dataclasses
 
     from repro.core import (CostEstimator, SearchConfig, SearchEngine,
@@ -46,19 +50,21 @@ def build_world(corpus: int, train_queries: int, queue_size: int, k: int,
 
     # equal contiguous slices require S | N
     corpus = -(-corpus // max(n_shards, 1)) * max(n_shards, 1)
-    ds = make_dataset(n=corpus, dim=48, n_clusters=16, alphabet_size=48,
-                      seed=seed)
+    if ds is None:
+        ds = make_dataset(n=corpus, dim=48, n_clusters=16, alphabet_size=48,
+                          seed=seed)
     if n_shards > 1:
         from repro.core.sharded import ShardedSearchEngine
         from repro.index.builder import build_sharded_graph_index
 
-        sgraph = build_sharded_graph_index(np.asarray(ds.vectors), n_shards,
-                                           degree=24, seed=seed)
-        graph = sgraph
-        engine = ShardedSearchEngine.build(ds, sgraph, backend=backend,
+        if graph is None:
+            graph = build_sharded_graph_index(np.asarray(ds.vectors),
+                                              n_shards, degree=24, seed=seed)
+        engine = ShardedSearchEngine.build(ds, graph, backend=backend,
                                            mesh=None, precision=precision)
     else:
-        graph = build_graph_index(ds.vectors, degree=24, seed=seed)
+        if graph is None:
+            graph = build_graph_index(ds.vectors, degree=24, seed=seed)
         engine = SearchEngine.build(ds, graph, backend=backend,
                                     precision=precision)
     cfg = SearchConfig(k=k, queue_size=queue_size, pred_kind=PRED_CONTAIN)
@@ -79,14 +85,16 @@ def build_world(corpus: int, train_queries: int, queue_size: int, k: int,
     return ds, graph, engine, cfg, est
 
 
-def mixed_requests(ds, n: int, seed: int = 100, hard_fraction: float = 0.5):
-    """Interleaved contain/range requests (heterogeneous difficulty)."""
+def mixed_requests(ds, n: int, seed: int = 100, hard_fraction: float = 0.5,
+                   selectivities: tuple = (0.01, 0.05, 0.10, 0.20)):
+    """Interleaved contain/range requests (heterogeneous difficulty);
+    `selectivities` are the range windows' global selectivities."""
     from repro.data import make_label_workload, make_range_workload
     from repro.serve import requests_from_workload
 
     wl_c = make_label_workload(ds, batch=(n + 1) // 2, kind="contain",
                                hard_fraction=hard_fraction, seed=seed)
-    wl_r = make_range_workload(ds, batch=n // 2,
+    wl_r = make_range_workload(ds, batch=n // 2, selectivities=selectivities,
                                hard_fraction=hard_fraction, seed=seed + 1)
     reqs = (requests_from_workload(wl_c, start_rid=0)
             + requests_from_workload(wl_r, start_rid=wl_c.batch))
@@ -96,6 +104,9 @@ def mixed_requests(ds, n: int, seed: int = 100, hard_fraction: float = 0.5):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch", type=int, default=16,

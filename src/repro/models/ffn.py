@@ -176,10 +176,7 @@ def _moe_forward_rowwise(cfg, p, x, return_aux=False):
         # *provably local* per data shard (GSPMD otherwise lowers the
         # cross-shard gather as full-result all-reduces; §Perf iter 4).
         from jax.sharding import PartitionSpec as PS
-        try:
-            from jax import shard_map as _shm
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as _shm
+        from jax import shard_map as _shm
 
         dp = PS(("pod", "data") if "pod" in mesh.shape else "data")
         row = PS(*dp, None)
@@ -236,10 +233,7 @@ def _moe_forward_rowwise(cfg, p, x, return_aux=False):
             return jnp.zeros((bl, s, d), cd).at[bi, stl].add(bk, mode="drop")
 
         from jax.sharding import PartitionSpec as PS
-        try:
-            from jax import shard_map as _shm
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as _shm
+        from jax import shard_map as _shm
         dp = PS(("pod", "data") if "pod" in mesh.shape else "data")
         row = PS(*dp, None)
         out = _shm(_combine, mesh=mesh,

@@ -280,7 +280,8 @@ def build_pq_lut(codebooks, queries):
     levels, s, kc, dsub = codebooks.shape
     q = jnp.asarray(queries, jnp.float32)
     qs = q.reshape(q.shape[0], s, dsub)
-    lut = jnp.einsum("bsd,lscd->blsc", qs, codebooks)
+    lut = jnp.einsum("bsd,lscd->blsc", qs, codebooks,
+                     precision=jax.lax.Precision.HIGHEST)
     return lut.reshape(q.shape[0], levels * s, kc)
 
 

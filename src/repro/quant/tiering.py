@@ -10,14 +10,13 @@ store leave the device entirely:
   HBM    compressed codes + norms + err, graph, packed attributes
   host   float32 vectors — `HostVectorStore`, streamed per rerank batch
 
-`HostVectorStore` keeps the primary copy as host numpy and *attempts* a
-`pinned_host` memory-kind placement so accelerator backends with memory
-tiers (TPU) DMA the gathered rows directly; backends without the tier
-(this container's XLA:CPU) fall back to a numpy row gather + one
-host→device transfer of the [B, P, d] result — semantically identical,
-bitwise identical rows. Either way the device never holds the [N, d]
-float32 array, which is the term that bounded N before tiering
-(float32 d=64 at 10M rows = 2.4 GiB vs 56 B/vec PQ = 0.5 GiB).
+`HostVectorStore` keeps the float32 store as host numpy. A rerank batch
+gathers its rows there and makes one host→device copy of the [B, P, d]
+result, so the device never holds the [N, d] float32 array, which is the
+term that bounded N before tiering (float32 d=64 at 10M rows = 2.4 GiB vs
+56 B/vec PQ = 0.5 GiB). There is no `pinned_host` placement: an XLA gather
+cannot mix a host-memory operand with device indices, and a failed
+placement must raise, not pick another path.
 
 `DeviceVectorStore` is the degenerate tier for small corpora and float32
 engines — same gather interface, vectors device-resident.
@@ -59,24 +58,6 @@ class HostVectorStore:
     def __init__(self, vectors, chunk_rows: int = 1 << 18):
         self._np = np.ascontiguousarray(np.asarray(vectors), np.float32)
         self._chunk = int(chunk_rows)
-        self._pinned = self._try_pin()
-
-    def _try_pin(self):
-        """Best-effort pinned-host placement for DMA-capable backends.
-
-        jax memory kinds are backend-dependent; a failed placement (XLA:CPU
-        has no pinned_host tier) silently selects the numpy gather path —
-        the returned rows are the same bytes either way.
-        """
-        try:
-            dev = jax.devices()[0]
-            sharding = jax.sharding.SingleDeviceSharding(
-                dev, memory_kind="pinned_host")
-            arr = jax.device_put(self._np, sharding)
-            arr.block_until_ready()
-            return arr
-        except Exception:
-            return None
 
     @property
     def shape(self):
@@ -93,8 +74,6 @@ class HostVectorStore:
         B·P·d floats per batch — independent of N. Very large requests
         stream in `chunk_rows` row-chunks to bound peak host scratch.
         """
-        if self._pinned is not None:
-            return self._pinned[jnp.maximum(jnp.asarray(idx), 0)]
         idx = np.maximum(np.asarray(idx), 0)
         flat = idx.reshape(-1)
         if flat.size <= self._chunk:

@@ -280,9 +280,9 @@ def test_persistent_kernel_interpret_parity(precision):
     for _ in range(u):
         host = step(host)
 
-    rows, aux = build_persistent_operands(precision, vecs, labels, values,
-                                          quant)
-    kern = persistent_multi_step(cfg, queries, prog, rows, aux, nbrs,
+    rows, aux, nbr_rows = build_persistent_operands(
+        precision, vecs, labels, values, nbrs, quant)
+    kern = persistent_multi_step(cfg, queries, prog, rows, aux, nbr_rows,
                                  budgets, st0, jnp.int32(10 ** 6), gt, qprep,
                                  steps=u, n_values=1, has_gt=True,
                                  interpret=True, block_b=4)
@@ -296,3 +296,26 @@ def test_persistent_kernel_interpret_parity(precision):
                                        atol=1e-5, err_msg=f)
         else:
             np.testing.assert_array_equal(a, b_, err_msg=f)
+
+
+@pytest.mark.parametrize("precision", ["float32", "int8"])
+def test_persistent_operands_packed_once_per_engine(world, precision):
+    """The kernel's lane-padded HBM operands are packed on first use and
+    kept on the engine (probe and resume reuse them); replacing a source
+    array repacks them."""
+    _, _, engines = world
+    eng = dataclasses.replace(engines[precision])  # a fresh operand cache
+    first = eng.persistent_operands(precision)
+    again = eng.persistent_operands(precision)
+    assert all(a is b for a, b in zip(first, again))
+    rows, aux, nbrs = first
+    n, r = eng.neighbors.shape
+    for a in first:
+        assert a.shape[0] == n and a.shape[1] % 128 == 0
+    np.testing.assert_array_equal(np.asarray(nbrs)[:, :r],
+                                  np.asarray(eng.neighbors))
+    assert not np.asarray(nbrs)[:, r:].any()
+    eng.neighbors = eng.neighbors + 0
+    repacked = eng.persistent_operands(precision)
+    assert repacked[2] is not nbrs
+    np.testing.assert_array_equal(np.asarray(repacked[2]), np.asarray(nbrs))
