@@ -271,7 +271,7 @@ def planned_search(
         trace_id = tr.new_trace("plan")
     prog = engine.compile(filt)
     if stats is None:
-        stats = scan_stats(engine, prog)
+        stats = scan_stats(engine, prog, tracer=tracer, trace_id=trace_id)
     queries = np.asarray(queries, np.float32)
     b = queries.shape[0]
     counts = stats.counts

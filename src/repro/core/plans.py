@@ -66,16 +66,34 @@ class ScanStats(NamedTuple):
 
 
 def scan_stats(engine: SearchEngine, prog: FilterProgram,
-               chunk: int = 2048) -> ScanStats:
-    """Compile the candidate bitmap + exact selectivity statistics."""
-    if getattr(engine, "is_sharded", False):
-        # index-axis-sharded engine: per-shard bitmap passes, one global
-        # ScanStats (core.sharded) — keeps the planner engine-agnostic
-        return engine.scan_stats(prog, chunk=chunk)
-    valid, frac = eval_program_matrix(prog, engine.label_attrs,
-                                      engine.value_attrs, chunk=chunk)
-    return ScanStats(valid=valid, counts=valid.sum(axis=1).astype(np.int64),
-                     clause_frac=frac, n=int(valid.shape[1]))
+               chunk: int = 2048, tracer=None, trace_id: str = "",
+               ) -> ScanStats:
+    """Compile the candidate bitmap + exact selectivity statistics.
+
+    `tracer` spans the bitmap pass as `filter-bitmap`: the rows, the
+    chunks evaluated one after another, the lanes, and the bytes copied
+    device to host (the bitmap and each pass's clause counts)."""
+    from repro.obs.trace import as_tracer
+
+    with as_tracer(tracer).span("filter-bitmap", trace_id) as sp:
+        if getattr(engine, "is_sharded", False):
+            # index-axis-sharded engine: per-shard bitmap passes, one
+            # global ScanStats (core.sharded) — keeps the planner
+            # engine-agnostic
+            stats = engine.scan_stats(prog, chunk=chunk)
+            passes, chunks = engine.n_shards, engine.n_shards * -(
+                -engine.shard_size // chunk)
+        else:
+            valid, frac = eval_program_matrix(prog, engine.label_attrs,
+                                              engine.value_attrs, chunk=chunk)
+            stats = ScanStats(valid=valid,
+                              counts=valid.sum(axis=1).astype(np.int64),
+                              clause_frac=frac, n=int(valid.shape[1]))
+            passes, chunks = 1, -(-stats.n // chunk)
+        sp.set(rows=stats.n, chunks=chunks, lanes=int(stats.valid.shape[0]),
+               bytes_to_host=int(stats.valid.nbytes
+                                 + passes * stats.clause_frac.nbytes))
+    return stats
 
 
 def _aligned_width(max_count: int, n: int) -> int:

@@ -18,6 +18,7 @@ the paper's filtered-RAG deployment story.
 from __future__ import annotations
 
 import argparse
+import collections
 import os
 import time
 
@@ -146,8 +147,10 @@ def main():
                          "the run")
     ap.add_argument("--explain", type=int, default=0, metavar="N",
                     help="trace request lifecycles and print the first N "
-                         "served timelines (admit → probe → resume slices "
-                         "→ complete)")
+                         "served timelines (admit → queued → probe → "
+                         "resume slices → complete), the programs "
+                         "compiled while serving, by span, and the host "
+                         "seconds of each span name")
     ap.add_argument("--trace-out", default=None,
                     help="stream lifecycle spans to this JSONL file")
     ap.add_argument("--prometheus", action="store_true",
@@ -230,6 +233,21 @@ def main():
                      if sp.duration > 0 else "")
                 print(f"  {1e3 * (sp.t0 - (r.arrival or 0.0)):8.1f}ms "
                       f"{sp.name}{t}{extras}")
+        comp = tracer.spans(name="compile")
+        by_span = collections.Counter(sp.attrs["inside"] or "-"
+                                      for sp in comp)
+        print(f"== programs compiled while serving: {tracer.n_compiles} "
+              f"({sum(sp.attrs['seconds'] for sp in comp):.3f} s); by "
+              "span: " + (", ".join(f"{k} {v}" for k, v in
+                                    by_span.most_common()) or "none"))
+        secs, n = collections.Counter(), collections.Counter()
+        for sp in tracer.spans():
+            if sp.duration > 0:
+                secs[sp.name] += sp.duration
+                n[sp.name] += 1
+        print("== host seconds by span over the run (nested spans overlap):"
+              " " + ", ".join(f"{k} {v:.3f} s/{n[k]}"
+                              for k, v in secs.most_common()))
     if tracer is not None:
         tracer.close()
 

@@ -26,6 +26,25 @@ Design constraints (pinned by tests/test_obs.py):
 The tracer is clock-injected like the serving scheduler: pass `clock=` to
 drive it from a virtual clock (benchmarks) or leave the default
 `time.perf_counter` (monotonic) for wall-clock tracing.
+
+Profiler bridge: every `span()` of a `Tracer` also enters
+`jax.profiler.TraceAnnotation("repro.<name>", trace=<trace id>)`, so while
+a JAX profiler trace runs the span lands in its xplane beside the device's
+operations; with no trace running the annotation records nothing.
+Instants and `emit()`ed intervals stay in the ring only. The xplane's
+clock starts at the trace's start while the ring's is `clock`, so a reader
+pairs the spans present in both (same name and trace id, in order) to fit
+the offset between the two clocks and then places ring-only events on the
+device timeline. `NullTracer` never touches `jax.profiler`.
+
+Compile counter: every live `Tracer` hears one process-wide
+`jax.monitoring` listener. Each program JAX lowers becomes one `compile`
+span (ring only) from the start of its lowering to the end of the backend
+compile that follows it; `seconds` is the two durations summed,
+`cache_miss` is False where the persistent compilation cache supplied the
+executable, and `inside` names the innermost span open on this tracer
+when it compiled (its trace id is that span's). `n_compiles` counts one
+per program lowered.
 """
 from __future__ import annotations
 
@@ -35,8 +54,10 @@ import itertools
 import json
 import os
 import time
+import weakref
 from collections import deque
 
+import jax
 import numpy as np
 
 #: ring default — ~100 B/span of attrs keeps this well under 10 MB
@@ -48,6 +69,39 @@ DEFAULT_CAPACITY = 1 << 16
 DEFAULT_SINK_MAX_BYTES = 64 << 20
 
 _SCALARS = (str, int, float, bool, type(None))
+
+# jax.monitoring events of one program's compilation, in the order they fire
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# live tracers the process-wide compile listener reports to
+_compile_tracers: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _listen_for_compiles(tracer: "Tracer") -> None:
+    """Register `tracer` with the one process-wide compile listener
+    (installed on first use; jax.monitoring listeners live as long as the
+    process, so a tracer is held weakly and drops out when collected)."""
+    global _compile_listening
+    _compile_tracers.add(tracer)
+    if _compile_listening:
+        return
+
+    def on_duration(event, duration, **_):
+        if event in (LOWER_EVENT, BACKEND_EVENT):
+            for t in list(_compile_tracers):
+                t._compile_event(event, duration)
+
+    def on_event(event, **_):
+        if event == CACHE_HIT_EVENT:
+            for t in list(_compile_tracers):
+                t._compile_event(event, 0.0)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    _compile_listening = True
 
 
 def _host_scalar(v):
@@ -102,6 +156,9 @@ class Tracer:
                  clock=time.perf_counter, sink: str | None = None,
                  sink_max_bytes: int = DEFAULT_SINK_MAX_BYTES):
         self.capacity = capacity
+        self._open: list[Span] = []     # spans entered and not yet left
+        self._compiling: Span | None = None  # lowered, backend not done
+        self.n_compiles = 0
         self.clock = clock
         self._ring: deque[Span] = deque(maxlen=capacity)
         self._ids = itertools.count(1)
@@ -112,6 +169,7 @@ class Tracer:
         self._sink = open(sink, "a") if sink else None
         # appending to a pre-existing file: count what's already there
         self._sink_bytes = self._sink.tell() if self._sink else 0
+        _listen_for_compiles(self)
 
     # ------------------------------------------------------------- ids ----
     def new_trace(self, prefix: str = "q") -> str:
@@ -136,14 +194,45 @@ class Tracer:
         discovered during the work (host scalars only)."""
         sp = Span(trace_id=trace_id, name=name, t0=self.clock(),
                   attrs={k: _host_scalar(v) for k, v in attrs.items()})
+        self._open.append(sp)
         try:
-            yield sp
+            with jax.profiler.TraceAnnotation(f"repro.{name}",
+                                              trace=trace_id):
+                yield sp
         finally:
             sp.t1 = self.clock()
+            self._open.pop()
             sp.attrs = {k: _host_scalar(v) for k, v in sp.attrs.items()}
             self._append(sp)
 
+    def _compile_event(self, event: str, duration: float) -> None:
+        """One jax.monitoring event of a compilation (see module doc)."""
+        if event == LOWER_EVENT:
+            self._flush_compile()
+            now = self.clock()
+            inner = self._open[-1] if self._open else None
+            self._compiling = Span(
+                trace_id=inner.trace_id if inner else "", name="compile",
+                t0=now - duration, t1=now,
+                attrs=dict(inside=inner.name if inner else "",
+                           seconds=float(duration), cache_miss=True))
+            self.n_compiles += 1
+        elif self._compiling is not None:
+            if event == CACHE_HIT_EVENT:
+                self._compiling.attrs["cache_miss"] = False
+            else:                                   # the backend compile
+                self._compiling.t1 = self.clock()
+                self._compiling.attrs["seconds"] += float(duration)
+                self._flush_compile()
+
+    def _flush_compile(self) -> None:
+        sp, self._compiling = self._compiling, None
+        if sp is not None:
+            self._append(sp)
+
     def _append(self, sp: Span) -> None:
+        if self._compiling is not None:
+            self._flush_compile()
         self._ring.append(sp)
         self.n_emitted += 1
         if self._sink is not None:
@@ -170,6 +259,7 @@ class Tracer:
     def spans(self, trace_id: str | None = None,
               name: str | None = None) -> list[Span]:
         """Spans still in the ring, oldest first, optionally filtered."""
+        self._flush_compile()
         return [s for s in self._ring
                 if (trace_id is None or s.trace_id == trace_id)
                 and (name is None or s.name == name)]
@@ -179,10 +269,12 @@ class Tracer:
 
     # ------------------------------------------------------------- sink ----
     def flush(self) -> None:
+        self._flush_compile()
         if self._sink is not None:
             self._sink.flush()
 
     def close(self) -> None:
+        self._flush_compile()
         if self._sink is not None:
             self._sink.close()
             self._sink = None

@@ -69,6 +69,8 @@ class Request:
     cache_hit: bool = False
     trace_id: str = ""                # obs lifecycle trace id (stamped at
                                       # submit when the scheduler traces)
+    queued_at: float | None = None    # tracer clock when the current wait
+                                      # in a queue began (tracing only)
     features: np.ndarray | None = None  # [F] probe feature vector the budget
                                       # prediction was made from (calibration)
     probe_ndc: int = 0                # NDC spent by the probe prefix

@@ -102,10 +102,14 @@ class CostAwareScheduler:
         "scan" (closed-form).
 
         tracer: optional `obs.Tracer`. Requests get trace ids at submit;
-        spans cover admit → probe → estimate → plan-select → resume
-        slices (per-launch spans from the persistent driver) → complete.
-        Spans wrap only host dispatch boundaries that already exist, so
-        results are bit-identical with tracing on vs. off.
+        spans cover admit → queued → probe → estimate → plan-select →
+        resume slices (per-launch spans from the persistent driver) →
+        rerank → complete, with the stage-0 `filter-bitmap` and the
+        batch assembly (`lanes`) of each pump. Each wait in the ingress
+        or a bucket queue is one `queued` span on the request's trace id,
+        naming the batch that took it. Spans wrap only host dispatch
+        boundaries that already exist, so results are bit-identical with
+        tracing on vs. off.
 
         calibration: record (features, predicted Ŵ_q, actual NDC, plan)
         per completed non-cache-hit request into `self.calibration` (a
@@ -293,6 +297,7 @@ class CostAwareScheduler:
             self._tr.emit("admit", req.trace_id, rid=req.rid, status=status)
             return status
         self._tr.emit("admit", req.trace_id, rid=req.rid, status="queued")
+        self._queued(req)
         return "queued"
 
     def has_work(self) -> bool:
@@ -300,6 +305,26 @@ class CostAwareScheduler:
 
     def depth(self) -> int:
         return len(self.ingress) + self.batcher.depth()
+
+    def _queued(self, req: Request) -> None:
+        """Start timing a wait in a queue (only while tracing)."""
+        if self.tracer is not None:
+            req.queued_at = self._tr.clock()
+
+    def _enqueue(self, req: Request, bucket: int | None) -> None:
+        self._queued(req)
+        self.batcher.enqueue(req, bucket)
+
+    def _took(self, reqs: list[Request], queue: str, batch: str) -> None:
+        """End the waits of `reqs`, taken from `queue` by `batch`: one
+        `queued` span each, on the request's own trace id."""
+        if self.tracer is None:
+            return
+        t1 = self._tr.clock()
+        for r in reqs:
+            self._tr.emit("queued", r.trace_id, t0=r.queued_at, t1=t1,
+                          rid=r.rid, queue=queue, batch=batch)
+            r.queued_at = None
 
     # --------------------------------------------------------------- pump ----
     def _dispatchable(self, now: float):
@@ -363,7 +388,8 @@ class CostAwareScheduler:
         return now
 
     # ---------------------------------------------------------- internals ----
-    def _final_results(self, queries, state, any_finish: bool = True):
+    def _final_results(self, queries, state, any_finish: bool = True,
+                       trace_id: str = ""):
         """Result arrays lanes finish with: the raw traversal buffers at
         float32 precision, the exact-reranked pool on a quantized engine.
 
@@ -378,8 +404,10 @@ class CostAwareScheduler:
         the same per-lane pools.
         """
         if self._rerank and any_finish:
-            rd, ri = self.engine.rerank_arrays(queries, state)
-            return np.asarray(ri), np.asarray(rd)
+            with self._tr.span("rerank", trace_id,
+                               width=int(queries.shape[0])):
+                rd, ri = self.engine.rerank_arrays(queries, state)
+                return np.asarray(ri), np.asarray(rd)
         return np.asarray(state.res_idx), np.asarray(state.res_dist)
 
     def _pump_probe(self, now: float) -> tuple[list[Request], float]:
@@ -392,16 +420,18 @@ class CostAwareScheduler:
         if scfg.plan == "scan":
             for r in reqs:
                 r.plan, r.plan_pure = "scan", True
-            return self._scan_batch(now, reqs, None)
+            return self._scan_batch(now, reqs, None, queue="ingress")
         if scfg.plan == "auto":
             return self._pump_auto(now, reqs)
         cfg = self.cfg  # one static config serves every filter structure
         t0 = self.timer()
         bt = self._tr.new_trace("probe") if self.tracer is not None else ""
+        self._took(reqs, "ingress", bt)
         l0 = self._launches0()
         width = self.batcher.width_for(len(reqs))
-        queries = self.batcher.pad_queries(reqs, width)
-        prog = self.batcher.pad_program(reqs, width)
+        with self._tr.span("lanes", bt, lanes=len(reqs), width=width):
+            queries = self.batcher.pad_queries(reqs, width)
+            prog = self.batcher.pad_program(reqs, width)
         lane_on = np.zeros(width, np.int32)
         lane_on[: len(reqs)] = 1
 
@@ -431,7 +461,7 @@ class CostAwareScheduler:
         self._observe_shards(st, None, len(reqs))
         res_idx, res_dist = self._final_results(
             queries, st,
-            any(int(budgets[i]) <= int(cnt[i]) for i in range(len(reqs))))
+            any(int(budgets[i]) <= int(cnt[i]) for i in range(len(reqs))), bt)
         lane_hops = np.asarray(st.hops)[: len(reqs)]
         steps = int(np.asarray(st.hops).max())  # lockstep trip count
         busy = (self.timer() - t0 if self.service_model is None
@@ -462,7 +492,7 @@ class CostAwareScheduler:
             else:
                 r.state = (st, i)   # lane reference into the probe batch
                 bucket = (0 if self.scfg.policy == "escalate" else None)
-                self.batcher.enqueue(r, bucket)
+                self._enqueue(r, bucket)
         return done, busy
 
     def _pump_auto(self, now: float, reqs: list[Request],
@@ -475,10 +505,13 @@ class CostAwareScheduler:
         scfg = self.scfg
         t0 = self.timer()
         bt = self._tr.new_trace("auto") if self.tracer is not None else ""
+        self._took(reqs, "ingress", bt)
         width = self.batcher.width_for(len(reqs))
-        prog = self.batcher.pad_program(reqs, width)
+        with self._tr.span("lanes", bt, lanes=len(reqs), width=width):
+            prog = self.batcher.pad_program(reqs, width)
         with self._tr.span("plan-stage0", bt, lanes=len(reqs)) as sp:
-            stats = scan_stats(self.engine, prog)
+            stats = scan_stats(self.engine, prog, tracer=self.tracer,
+                               trace_id=bt)
             self._observe_shard_bitmap(stats, len(reqs))
             s0 = np.asarray(stage0_scan_mask(
                 self.planner, stats, prog, scfg.alpha, scfg.min_budget,
@@ -516,8 +549,9 @@ class CostAwareScheduler:
         bt = self._tr.new_trace("probe") if self.tracer is not None else ""
         l0 = self._launches0()
         width = self.batcher.width_for(len(reqs))
-        queries = self.batcher.pad_queries(reqs, width)
-        prog = self.batcher.pad_program(reqs, width)
+        with self._tr.span("lanes", bt, lanes=len(reqs), width=width):
+            queries = self.batcher.pad_queries(reqs, width)
+            prog = self.batcher.pad_program(reqs, width)
         lane_on = np.zeros(width, np.int32)
         lane_on[: len(reqs)] = 1
         st, feats = probe_and_features(
@@ -536,7 +570,7 @@ class CostAwareScheduler:
         fin = [i for i in range(len(reqs)) if ids[i] != PLAN_SCAN
                and int((w_t if ids[i] == PLAN_TRAVERSE else w_w)[i])
                <= int(cnt[i])]
-        res_idx, res_dist = self._final_results(queries, st, bool(fin))
+        res_idx, res_dist = self._final_results(queries, st, bool(fin), bt)
         lane_hops = np.asarray(st.hops)[: len(reqs)]
         steps = int(np.asarray(st.hops).max())
         busy = (self.timer() - t0 if self.service_model is None
@@ -560,8 +594,9 @@ class CostAwareScheduler:
                 # NOT bitwise the forced-scan path (cnt differs), so no
                 # dual-put under the forced key
                 r.plan, r.plan_pure = "scan", False
-            d, b = self._scan_batch(now, sub, stats.rows(late),
-                                    base=take_lanes(st, np.asarray(late)))
+            with self._tr.span("lanes", bt, lanes=len(late)):
+                base = take_lanes(st, np.asarray(late))
+            d, b = self._scan_batch(now, sub, stats.rows(late), base=base)
             done += d
             busy += b
         for i, r in enumerate(reqs):
@@ -580,40 +615,48 @@ class CostAwareScheduler:
             else:
                 r.state = (st, i)
                 bucket = (0 if self.scfg.policy == "escalate" else None)
-                self.batcher.enqueue(r, bucket)
+                self._enqueue(r, bucket)
         return done, busy
 
     def _scan_batch(self, now: float, reqs: list[Request], stats,
-                    base=None) -> tuple[list[Request], float]:
+                    base=None, queue: str | None = None,
+                    ) -> tuple[list[Request], float]:
         """Execute the terminal scan plan for a group of requests. `stats`
         is the lanes' ScanStats rows (None → compile here, the forced-scan
-        path); `base` carries probe states for late-scan lanes. The batch
-        pads to the lane-width ladder like every other micro-batch — the
-        per-lane-deterministic scan distance path makes the padding (and
-        any batch composition) invisible in the results."""
+        path); `base` carries probe states for late-scan lanes; `queue`
+        names the queue the requests were just taken from, if this batch
+        took them. The batch pads to the lane-width ladder like every
+        other micro-batch — the per-lane-deterministic scan distance path
+        makes the padding (and any batch composition) invisible in the
+        results."""
         t0 = self.timer()
         bt = self._tr.new_trace("scan") if self.tracer is not None else ""
+        if queue is not None:
+            self._took(reqs, queue, bt)
         width = self.batcher.width_for(len(reqs))
-        queries = self.batcher.pad_queries(reqs, width)
-        prog = self.batcher.pad_program(reqs, width)
         pad = width - len(reqs)
+        with self._tr.span("lanes", bt, lanes=len(reqs), width=width):
+            queries = self.batcher.pad_queries(reqs, width)
+            prog = self.batcher.pad_program(reqs, width)
+            if stats is not None and pad:
+                stats = ScanStats(
+                    valid=np.pad(stats.valid, ((0, pad), (0, 0))),
+                    counts=np.pad(stats.counts, (0, pad)),
+                    clause_frac=np.pad(stats.clause_frac,
+                                       ((0, pad), (0, 0))),
+                    n=stats.n)
+            if base is not None and pad:
+                base = pad_lanes(base, pad)
         if stats is None:
-            stats = scan_stats(self.engine, prog)  # pads match nothing
+            stats = scan_stats(self.engine, prog, tracer=self.tracer,
+                               trace_id=bt)          # pads match nothing
             self._observe_shard_bitmap(stats, len(reqs))
-        elif pad:
-            stats = ScanStats(
-                valid=np.pad(stats.valid, ((0, pad), (0, 0))),
-                counts=np.pad(stats.counts, (0, pad)),
-                clause_frac=np.pad(stats.clause_frac, ((0, pad), (0, 0))),
-                n=stats.n)
-        if base is not None and pad:
-            base = pad_lanes(base, pad)
         with self._tr.span("scan", bt, lanes=len(reqs), width=width,
                            late=base is not None):
             st = scan_search(self.engine, self.cfg, queries, prog,
                              stats=stats, base_state=base)
             jax.block_until_ready(st.res_dist)
-        res_idx, res_dist = self._final_results(queries, st, True)
+        res_idx, res_dist = self._final_results(queries, st, True, bt)
         cnt = np.asarray(st.cnt)
         self._observe_shards(st, base, len(reqs))
         # scan has no lockstep trips; charge the service model the
@@ -645,12 +688,14 @@ class CostAwareScheduler:
         cfg = self.cfg_widen if plan == "widen" else self.cfg
         t0 = self.timer()
         bt = self._tr.new_trace("bucket") if self.tracer is not None else ""
+        self._took(reqs, "bucket", bt)
         l0 = self._launches0()
         width = self.batcher.width_for(len(reqs))
-        queries = self.batcher.pad_queries(reqs, width)
-        prog = self.batcher.pad_program(reqs, width)
-        budgets = self.batcher.pad_budgets(reqs, cap, width)
-        state = self.batcher.pad_states(reqs, width)
+        with self._tr.span("lanes", bt, lanes=len(reqs), width=width):
+            queries = self.batcher.pad_queries(reqs, width)
+            prog = self.batcher.pad_program(reqs, width)
+            budgets = self.batcher.pad_budgets(reqs, cap, width)
+            state = self.batcher.pad_states(reqs, width)
 
         # Stage 3 — adaptive termination, bounded by the bucket cap.
         entry_hops = np.asarray(state.hops)
@@ -665,7 +710,7 @@ class CostAwareScheduler:
             sp.set(steps=steps)
         res_idx, res_dist = self._final_results(
             queries, out,
-            cap is None or any(r.budget <= cap for r in reqs))
+            cap is None or any(r.budget <= cap for r in reqs), bt)
         cnt = np.asarray(out.cnt)
         self._observe_shards(out, state, len(reqs))
         targets = np.asarray(budgets)
@@ -689,7 +734,7 @@ class CostAwareScheduler:
                 # preemption: bounded slice done, requeue the carried state
                 r.state = (out, i)
                 nxt = (idx + 1 if self.scfg.policy == "escalate" else None)
-                self.batcher.enqueue(r, nxt)
+                self._enqueue(r, nxt)
         return done, busy
 
     def _finish(self, req: Request, res_idx, res_dist, ndc, at: float):

@@ -3,6 +3,7 @@ tracing-changes-nothing contract (bit-identical results, no extra device
 dispatches), EXPLAIN termination semantics, calibration telemetry schema +
 persistence, Prometheus exposition validity, ServeMetrics hardening, and
 the scheduler's driver-observed launch accounting."""
+import contextlib
 import json
 import types
 
@@ -68,6 +69,56 @@ def test_span_attrs_must_be_host_scalars():
     with pytest.raises(TypeError):
         with tr.span("bad", "t") as sp:
             sp.set(arr=np.zeros(4))                 # ... via sp.set either
+
+
+def test_only_a_real_tracer_annotates(monkeypatch):
+    """NullTracer never calls jax.profiler; a Tracer enters one annotation
+    for every span, named after it and carrying its trace id, and keeps
+    instants and emitted intervals in the ring only."""
+    import jax
+
+    def boom(*a, **k):
+        raise AssertionError("jax.profiler.TraceAnnotation called")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    with NO_TRACE.span("lanes", "q-1"):
+        NO_TRACE.emit("admit", "q-1")
+    seen = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **kw: seen.append((name, kw))
+                        or contextlib.nullcontext())
+    tr = Tracer()
+    with tr.span("resume", "bucket-2"):
+        with tr.span("launch", "bucket-2"):
+            pass
+    tr.emit("admit", "req-1")                           # ring only
+    tr.emit("queued", "req-1", t0=0.0, t1=1.0)          # ring only
+    assert seen == [("repro.resume", {"trace": "bucket-2"}),
+                    ("repro.launch", {"trace": "bucket-2"})]
+    assert [s.name for s in tr.spans()] == ["launch", "resume", "admit",
+                                            "queued"]
+
+
+def test_compile_counted_once_inside_innermost_span():
+    """Each program lowered is one `compile` span and one count, however
+    many jax.monitoring events its compilation fires, attributed to the
+    innermost span open when it compiled."""
+    import jax
+
+    tr = Tracer()
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    f(np.arange(5, dtype=np.float32))                # outside any span
+    with tr.span("resume", "bucket-1"):
+        with tr.span("lanes", "bucket-1"):
+            f(np.arange(5, dtype=np.float32))        # cached: no compile
+            f(np.arange(7, dtype=np.float32))        # new shape: recompile
+    assert tr.n_compiles == 2
+    outside, inside = tr.spans(name="compile")
+    assert outside.attrs["inside"] == "" and outside.trace_id == ""
+    assert inside.attrs["inside"] == "lanes"
+    assert inside.trace_id == "bucket-1"
+    assert inside.attrs["seconds"] > 0 and inside.duration > 0
+    assert isinstance(inside.attrs["cache_miss"], bool)
 
 
 def test_null_tracer_is_inert():

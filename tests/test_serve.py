@@ -451,3 +451,58 @@ def test_scheduler_reports_shed_and_metrics_json(world):
     assert s["n_completed"] == 4 and s["n_shed"] == 2
     assert s["latency"]["p50"] <= s["latency"]["p99"]
     json.dumps(s)  # BENCH artifact requirement: plain-JSON serializable
+
+
+def test_profiler_bridged_tracing_changes_nothing(world, auto_planner,
+                                                   tmp_path):
+    """A `Tracer` bridged into a running JAX profiler trace leaves
+    the auto plan's answers bit-identical (an int8 store, so finishing
+    lanes go through the float32 rerank), spans the stage-0 bitmap, batch
+    assembly and rerank, and closes every wait in a queue once, naming
+    the batch that took the request."""
+    import jax
+
+    from repro.index.graph import GraphIndex
+    from repro.obs import Tracer
+
+    ds, engine, cfg, est = world
+    graph = GraphIndex(neighbors=np.asarray(engine.neighbors),
+                       entry_point=engine.entry_point, dim=ds.dim)
+    eng8 = SearchEngine.build(ds, graph, precision="int8")
+    scfg = ServeConfig(lane_width=8, buckets=(256, None), probe_budget=48,
+                       plan="auto", cache_capacity=0)
+    wl = make_composite_workload(ds, batch=12, seed=21, structure="mixed",
+                                 selectivities=(0.01, 0.3))
+
+    def serve(tracer):
+        sched = CostAwareScheduler(eng8, est, cfg, scfg,
+                                   planner=auto_planner, tracer=tracer)
+        reqs = requests_from_workload(wl)
+        for r in reqs:
+            assert sched.submit(r, 0.0) == "queued"
+        sched.run_until_idle(0.0)
+        return reqs
+
+    plain = serve(None)
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        traced = serve(tr)
+    for a, b in zip(plain, traced):
+        assert np.array_equal(a.res_idx, b.res_idx)
+        assert np.array_equal(a.res_dist, b.res_dist)
+        assert (a.ndc, a.plan) == (b.ndc, b.plan)
+
+    names = {s.name for s in tr.spans()}
+    assert {"lanes", "plan-stage0", "filter-bitmap", "rerank",
+            "queued"} <= names
+    bitmap = tr.spans(name="filter-bitmap")[0].attrs
+    assert bitmap["rows"] == len(ds.vectors)
+    assert bitmap["chunks"] == -(-len(ds.vectors) // 2048)
+    assert bitmap["bytes_to_host"] > bitmap["lanes"] * bitmap["rows"]
+    waits = tr.spans(name="queued")
+    ingress = [s for s in waits if s.attrs["queue"] == "ingress"]
+    assert sorted(s.attrs["rid"] for s in ingress) == [r.rid for r in traced]
+    assert (len(waits) - len(ingress)
+            == sum(r.n_slices for r in traced))      # one per bucket slice
+    assert all(s.duration >= 0 and s.attrs["batch"] for s in waits)
+    assert all(r.queued_at is None for r in traced)
