@@ -2,15 +2,23 @@
 
 For every completed request the reference gives the exact filtered top-k
 and the exact distance of each id the program returned. The numbers
-compared, each against the cell's limit (`bench/limits/<cell>.json`):
+compared, each against the cell's limit (`bench/limits/<cell>.json`),
+cover every completed submission of the window, replays included:
 
   bad_ids           returned ids that are out of range, repeated, or fail
                     their request's filter (limit 0: an exact guarantee)
-  unfinished        requests due in the window that never completed, a
-                    minute past its close (limit 0)
+  unfinished        requests due in the window (a closed loop: every
+                    submission, and every request of its recall set) that
+                    never completed, a minute past its close (limit 0)
   dist_err_max      largest |returned distance - exact distance| over the
                     exact distance (floored at 1e-3), over all returned ids
   recall_loss       1 - mean recall@k over all completed requests
+
+The end-to-end reading `recall_at_10` (`bench/lib/harness.py`) covers,
+in a closed loop, a fixed set instead: its first `recall_requests`
+submissions, the same requests for every seed and at every rate, where
+one that never completed counts as recall 0. In an open loop it is the
+mean over the completed requests due in the window.
 
 Recall counts a returned id as a hit when it passes its filter and its
 exact distance is within rounding of the reference's k-th distance, so a

@@ -27,7 +27,8 @@ import numpy as np
 from bench.lib import compare, data, graph, manifest, peaks
 from bench.lib import trace as tracelib
 from bench.lib.reference import Reference
-from bench.lib.traffic import TrafficGen, block_order, gaps
+from bench.lib.traffic import (TrafficGen, block_order, closed_pool, gaps,
+                                recall_requests)
 
 DRAIN_S = 60.0
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -170,17 +171,31 @@ def _close(on_close) -> float:
     return time.perf_counter() - t
 
 
-def serve_closed(sched, reqs, outstanding: int, until: float | None,
-                 annotate=None, on_close=None):
-    """Closed loop: `outstanding` requests in flight, the next submitted
-    as one completes, until `until` seconds (None: until `reqs` run out),
+def serve_closed(sched, batch, outstanding: int, until: float | None,
+                 annotate=None, on_close=None, finish: int = 0,
+                 start_rid: int = 0):
+    """Closed loop over the P requests of `batch`: `outstanding` in
+    flight, the next submitted as one completes, until `until` seconds,
     then `on_close()` once, between pumps, and the drain of what is in
-    flight. Returns (completion seconds per request, nan where not
-    completed, number submitted)."""
+    flight. A timed loop replays the batch: submission j serves entry
+    j mod P, as a new request with its own rid, so the loop ends at the
+    close and never because the batch ran out. During the drain it still
+    submits, up to the first `finish` submissions and only those. An
+    untimed one (`until` None) serves the batch once. Returns (the
+    requests submitted, in order; the seconds each was submitted at; the
+    seconds each completed at, nan where it did not)."""
+    from repro.serve import Request
+
     ann = annotate or _no_annotation
-    done_at = np.full(len(reqs), np.nan)
+    n = len(batch.exprs)
+    subs, sent_at, done_at = [], [], []
+
+    def wanted(open_):
+        j = len(subs)
+        return j < finish or (open_ and (until is not None or j < n))
+
     t0 = time.perf_counter()
-    nxt = inflight = 0
+    inflight = 0
     grace = 0.0
     while True:
         now = time.perf_counter() - t0
@@ -188,23 +203,28 @@ def serve_closed(sched, reqs, outstanding: int, until: float | None,
         if not open_:
             grace += _close(on_close)
             on_close = None
-        while open_ and inflight < outstanding and nxt < len(reqs):
+        while inflight < outstanding and wanted(open_):
+            i = len(subs) % n
+            req = Request(rid=start_rid + len(subs), query=batch.queries[i],
+                          expr=batch.exprs[i])
             with ann("bench.submit"):
-                sched.submit(reqs[nxt], now)
-            nxt += 1
+                sched.submit(req, now)
+            subs.append(req)
+            sent_at.append(now)
+            done_at.append(np.nan)
             inflight += 1
         if sched.has_work():
             with ann("bench.pump"):
                 done, _ = sched.pump(now)
             t = time.perf_counter() - t0
             for r in done:
-                done_at[r.rid - reqs[0].rid] = t
+                done_at[r.rid - start_rid] = t
             inflight -= len(done)
-        elif not open_ or nxt >= len(reqs):
+        elif not wanted(open_):
             break
         if until is not None and now > until + DRAIN_S + grace:
             break
-    return done_at, nxt
+    return subs, np.asarray(sent_at), np.asarray(done_at, np.float64)
 
 
 def serve_open(sched, reqs, due, seconds: float, annotate=None,
@@ -269,9 +289,8 @@ def warm_up(w: World, gen: TrafficGen, mix: dict, pool_seed: int,
     rng = np.random.default_rng([seed, 1])
     total = 0
     for depth, count in mix["warmup"]:
-        reqs = make_requests(ordered(gen, int(count), pool, rng),
-                             start_rid=10_000_000 + total)
-        serve_closed(new_scheduler(w), reqs, int(depth), None)
+        serve_closed(new_scheduler(w), ordered(gen, int(count), pool, rng),
+                     int(depth), None, start_rid=10_000_000 + total)
         total += int(count)
     sec = float(mix.get("warmup_open_seconds", 0))
     if sec > 0:
@@ -308,7 +327,7 @@ def window_requests(mix: dict, gen: TrafficGen, pool_seed: int, seed: int,
         n = int(round(mix["rate_qps"] * seconds))
         due = np.cumsum(gaps(n, mix["rate_qps"], rng))
     else:
-        n = int(mix["outstanding"] + np.ceil(mix["pool_qps"] * seconds))
+        n = closed_pool(mix, seconds)
     return ordered(gen, n, pool, rng), due
 
 
@@ -320,6 +339,12 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     cell = manifest.load_cell(cell_name, root)
     cfg = deep_merge(cell.config, (overrides or {}).get("config", {}))
     mix = deep_merge(cell.traffic, (overrides or {}).get("traffic", {}))
+    closed = mix["loop"] == "closed"
+    n_recall = recall_requests(mix, seconds) if closed else None
+    if closed and cfg["serve"].get("cache_capacity") != 0:
+        raise ValueError("a closed loop replays its pool, so the result "
+                         "cache, keyed on the query, has to be off "
+                         "(serve.cache_capacity 0)")
     import jax
 
     devs = jax.devices()
@@ -347,7 +372,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     batch, due = window_requests(mix, gen, cfg["deployment_seed"], seed,
                                  seconds)
     n = len(batch.exprs)
-    reqs = make_requests(batch)
+    reqs = None if closed else make_requests(batch)
     sched = new_scheduler(w)
     w.split["requests"] = time.perf_counter() - t
     setup_s = time.perf_counter() - t_start
@@ -378,28 +403,43 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     if trace:
         closing.callback(stop_trace)
     closing.enter_context((annotate or _no_annotation)("bench.window"))
-    if mix["loop"] == "open":
+    if closed:
+        reqs, sent_at, done_at = serve_closed(
+            sched, batch, int(mix["outstanding"]), seconds, annotate,
+            closing.close, finish=n_recall)
+    else:
         done_at, late = serve_open(sched, reqs, due, seconds, annotate,
                                    closing.close)
-    else:
-        done_at, n_sub = serve_closed(sched, reqs, int(mix["outstanding"]),
-                                      seconds, annotate, closing.close)
     closing.close()
     counter.on = False
     bodies1 = dispatch_counters()["bodies"]
     mem = memory_peak(jax)
 
-    if mix["loop"] == "open":
+    # `entry`: the pool entry each submission served; `in_window`: the
+    # submissions judged for `correct`; `recall_set`: those a closed
+    # loop's recall_at_10 averages over
+    n_sub = len(reqs)
+    missing = 0
+    if closed:
+        entry = np.arange(n_sub) % n
+        in_window = np.arange(n_sub)
+        recall_set = np.arange(min(n_recall, n_sub))
+        missing = n_recall - len(recall_set)
+        after = int((sent_at[recall_set] >= seconds).sum())
+        lat = None
+        log(f"closed loop: pool of {n} distinct requests, {n_sub} "
+            f"submissions, {max(0, n_sub - n)} replays; recall set: the "
+            f"first {n_recall}, {after} of them submitted after the close, "
+            f"{missing} never submitted")
+    else:
+        entry = np.arange(n)
         in_window = np.arange(n)[due <= seconds]
         lat = done_at[in_window] - due[in_window]
         log(f"generator lateness: mean {1e3 * late.mean():.3f} ms, max "
             f"{1e3 * late.max(initial=0):.3f} ms over {n} submissions")
-    else:
-        in_window = np.arange(n_sub)
-        lat = None
     completed = in_window[np.isfinite(done_at[in_window])]
     n_done_window = int((done_at[in_window] <= seconds).sum())
-    unfinished = len(in_window) - len(completed)
+    unfinished = len(in_window) - len(completed) + missing
     log(f"window: {len(in_window)} requests due, {len(completed)} "
         f"completed ({n_done_window} inside {seconds} s); programs "
         f"compiled inside the window: {counter.count} "
@@ -433,24 +473,34 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     del w
     gc.collect()
 
+    # the exact search once per distinct pool entry, so its cost stays
+    # bounded by the pool whatever the rate; each answer scored apart
     t = time.perf_counter()
     ref = Reference(dep.vectors, dep.labels_packed, dep.values)
-    filt = batch.filters.take(completed)
-    ref_ids, ref_d = ref.search(batch.queries[completed], filt,
-                                cfg["search"]["k"])
-    true_d, ok = ref.score(batch.queries[completed], ids, filt)
-    nums, rec = compare.numbers(ids, dist, true_d, ok, ref_ids, ref_d,
-                                dep.n, unfinished)
+    served = entry[completed]
+    uniq, inv = np.unique(served, return_inverse=True)
+    ref_ids, ref_d = ref.search(batch.queries[uniq],
+                                batch.filters.take(uniq), cfg["search"]["k"])
+    true_d, ok = ref.score(batch.queries[served], ids,
+                           batch.filters.take(served))
+    nums, rec = compare.numbers(ids, dist, true_d, ok, ref_ids[inv],
+                                ref_d[inv], dep.n, unfinished)
     correct, checks = compare.judge(nums, cell.limits)
-    log(f"reference over {len(completed)} requests: "
-        f"{time.perf_counter() - t:.3f} s; numbers (those with a limit "
-        f"are compared): {json.dumps(nums)}")
+    log(f"reference: exact search over {len(uniq)} distinct requests, "
+        f"{len(completed)} answers scored: {time.perf_counter() - t:.3f} "
+        f"s; numbers (those with a limit are compared): {json.dumps(nums)}")
+    if closed:
+        # a request of the recall set that never completed counts as 0
+        recall_at_10 = float(rec[np.isin(completed, recall_set)].sum()
+                             / n_recall)
+    else:
+        recall_at_10 = float(rec.mean()) if rec.size else 0.0
 
     metrics = {}
     if not trace:
         values = {"setup_s": setup_s,
                   "qps": n_done_window / seconds,
-                  "recall_at_10": float(rec.mean()) if rec.size else 0.0}
+                  "recall_at_10": recall_at_10}
         if lat is not None:
             full = np.where(np.isfinite(lat), lat, np.inf)
             values["p50_ms"] = 1e3 * float(np.quantile(full, 0.5))

@@ -6,8 +6,18 @@ A mix file (`bench/traffic/<mix>.json`) holds:
                     number of requests outstanding)
   rate_qps          open loop: offered rate
   outstanding       closed loop: requests in flight
-  pool_qps          closed loop: requests made ready per window second
-                    (more than the cell completes)
+  pool_qps          closed loop: distinct requests drawn per window
+                    second; the window's pool holds P = outstanding +
+                    ceil(pool_qps x seconds) of them, and submission j
+                    serves pool entry j mod P, so a loop that completes
+                    more than P replays the pool instead of running dry
+  recall_requests   closed loop: `recall_at_10` is the mean recall over
+                    the first this many submissions (whole blocks of
+                    `order_block`, at most P): the same requests for
+                    every seed and at every rate. Submissions of this set
+                    still due at the window's close go out during the
+                    drain; they count toward recall and `correct`, never
+                    toward `qps`
   warmup            [[depth, count], ...]: requests served closed-loop at
                     each depth before the window, to compile its shapes
   warmup_open_seconds  open loop: then this long at `rate_qps` (the lane
@@ -35,6 +45,12 @@ benchmark's query file is fixed; a run's seed draws their order (within
 blocks of `order_block`) and the order of the inter-arrival gaps. So
 every seed gets the same queries, filters and gaps, in another order:
 a seed changes the order of the work, not the work.
+
+A replayed request is a new request (its own id) with the same query
+and filter as its pool entry: the same work as a fresh one only while
+nothing in the program is keyed on the query. So a closed mix may
+replay only where no cache keyed on the query is on: the harness refuses
+a closed loop unless the configuration's `serve.cache_capacity` is 0.
 """
 from __future__ import annotations
 
@@ -100,6 +116,24 @@ def gaps(n: int, rate: float, rng) -> np.ndarray:
     g = -np.log1p(-u) / rate
     rng.shuffle(g)
     return g
+
+
+def closed_pool(mix: dict, seconds: float) -> int:
+    """P: the distinct requests a closed loop's window draws."""
+    return int(mix["outstanding"] + np.ceil(mix["pool_qps"] * seconds))
+
+
+def recall_requests(mix: dict, seconds: float) -> int:
+    """The closed loop's recall set size; refuses one that is not a
+    positive whole number of `order_block` blocks within the pool."""
+    r, block = mix["recall_requests"], int(mix["order_block"])
+    pool = closed_pool(mix, seconds)
+    if not isinstance(r, int) or r <= 0 or r % block or r > pool:
+        raise ValueError(
+            f"recall_requests {r!r} must be a positive multiple of "
+            f"order_block {block} and at most the pool of {pool} requests "
+            f"(outstanding + ceil(pool_qps x {seconds} s))")
+    return r
 
 
 class TrafficGen:

@@ -48,9 +48,8 @@ def main(argv=None) -> int:
     rng = np.random.default_rng([args.seed, 3])
     for depth in [int(x) for x in args.outstanding.split(",") if x]:
         n = depth + int(50 * args.seconds)
-        reqs = harness.make_requests(gen.batch(n, rng))
-        done_at, n_sub = harness.serve_closed(harness.new_scheduler(w), reqs,
-                                              depth, args.seconds)
+        _, _, done_at = harness.serve_closed(
+            harness.new_scheduler(w), gen.batch(n, rng), depth, args.seconds)
         qps = float((done_at <= args.seconds).sum() / args.seconds)
         print(json.dumps({"loop": "closed", "outstanding": depth,
                           "qps": qps}), flush=True)

@@ -3,12 +3,17 @@
 comes out correct; its control and a truncated reference fail its
 limits; and with the timed path broken underneath (an answer altered
 where the scheduler produces it, half of every answer dropped, the
-traversal stopped after the probe) the run comes out not correct. The
-command itself exits non-zero, printing no result, without a TPU."""
+traversal stopped after the probe) the run comes out not correct. A
+closed loop that completes more than its pool replays it, a slow one
+still serves its whole recall set, and a recall set that is not whole
+blocks within the pool is refused. The command itself exits non-zero,
+printing no result, without a TPU."""
 import dataclasses
 import json
+import re
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -22,7 +27,7 @@ TINY = {"config": {"data": {"n": 2048},
                                 "planner_queries": 32, "planner_trees": 8,
                                 "chunk": 32}},
         "traffic": {"warmup": [[8, 12], [1, 2]], "outstanding": 8,
-                    "pool_qps": 16}}
+                    "pool_qps": 16, "order_block": 8, "recall_requests": 16}}
 # a second, selective mix over the same rows: one label AND a value
 # window holding 20-60 of its rows
 SCAN = harness.deep_merge(TINY, {"traffic": {"filters": [{
@@ -110,6 +115,72 @@ def test_probe_only_traversal_is_caught():
     assert not out["correct"]
     check = out["checks"]["recall_loss"]
     assert check["value"] > check["limit"]
+
+
+def closed_loop_log(err: str) -> dict:
+    """The numbers of a run's `closed loop:` log line."""
+    m = re.search(r"closed loop: pool of (\d+) distinct requests, (\d+) "
+                  r"submissions, (\d+) replays; recall set: the first "
+                  r"(\d+), (\d+) of them submitted after the close, (\d+) "
+                  r"never submitted", err)
+    keys = ("pool", "submissions", "replays", "recall_requests",
+            "recall_after_close", "never_submitted")
+    return dict(zip(keys, map(int, m.groups())))
+
+
+def test_closed_loop_replays_its_pool(capsys):
+    """outstanding 8 and pool_qps 0.5 over 1 s: a pool of P = 9 distinct
+    requests, which the loop replays until the window closes."""
+    over = harness.deep_merge(TINY, {"traffic": {
+        "pool_qps": 0.5, "recall_requests": 8}})
+    out = harness.run(CELL, 2**31 + 13, 1.0, False, allow_cpu=True,
+                      overrides=over)
+    assert out["correct"], out["checks"]
+    win = closed_loop_log(capsys.readouterr().err)
+    assert win["pool"] == 9 and out["attempted"] > 9
+    assert win["submissions"] == out["attempted"]
+    assert win["replays"] == out["attempted"] - 9
+    assert win["recall_requests"] == 8 and out["failed"] == 0
+
+
+def test_recall_set_is_served_after_a_slow_close(monkeypatch, capsys):
+    """Every pump takes longer than the window: the close comes with only
+    the first `outstanding` of the 16-request recall set submitted. The
+    drain submits the rest of the set and nothing more; none of it counts
+    toward `qps`, all of it toward recall and `correct`."""
+    from repro.serve import scheduler
+
+    pump = scheduler.CostAwareScheduler.pump
+
+    def slow_pump(self, now):
+        time.sleep(1.05)
+        return pump(self, now)
+
+    monkeypatch.setattr(scheduler.CostAwareScheduler, "pump", slow_pump)
+    over = harness.deep_merge(TINY, {"traffic": {"warmup": [[8, 8]]}})
+    out = harness.run(CELL, 2**31 + 14, 1.0, False, allow_cpu=True,
+                      overrides=over)
+    assert out["correct"], out["checks"]
+    win = closed_loop_log(capsys.readouterr().err)
+    assert win["recall_requests"] == 16 and win["recall_after_close"] == 8
+    assert out["attempted"] == 16 and out["failed"] == 0
+    assert win["replays"] == 0 and win["never_submitted"] == 0
+    assert out["metrics"]["qps"]["value"] == 0.0
+    assert 0.0 < out["metrics"]["recall_at_10"]["value"] <= 1.0
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"traffic": {"recall_requests": 12}}, "recall_requests"),
+    ({"traffic": {"recall_requests": 32}}, "recall_requests"),
+    ({"traffic": {"recall_requests": 0}}, "recall_requests"),
+    ({"config": {"serve": {"cache_capacity": 64}}}, "cache"),
+], ids=["not_whole_blocks", "above_pool", "empty", "query_cache"])
+def test_mix_is_refused_before_set_up(over, match):
+    """TINY's pool over 1 s is 8 + 16 = 24 requests in blocks of 8; a
+    replayed request would hit a result cache keyed on the query."""
+    with pytest.raises(ValueError, match=match):
+        harness.run(CELL, 2**31 + 15, 1.0, False, allow_cpu=True,
+                    overrides=harness.deep_merge(TINY, over))
 
 
 def test_command_refuses_the_cpu(tmp_path):

@@ -1,9 +1,11 @@
 """Every seed gets the deployment's requests, in another order: the same
-queries and filters within each block of the mix's `order_block`."""
+queries and filters within each block of the mix's `order_block`, so
+the same recall set; a closed loop replays its pool in order."""
 import numpy as np
 
 from bench.lib import data, harness, manifest
-from bench.lib.traffic import TrafficGen, block_order
+from bench.lib.traffic import (TrafficGen, block_order, closed_pool,
+                               recall_requests)
 
 CELL = "sift1m-eq12-int8.eq.closed"
 
@@ -37,3 +39,51 @@ def test_seeds_reorder_the_same_window_requests():
         assert ka == kb
     again, _ = harness.window_requests(mix, gen, pool, 2**31 + 3, 2.0)
     np.testing.assert_array_equal(again.queries, a.queries)
+
+
+def _rows(batch, sl):
+    return sorted(map(tuple, np.c_[batch.queries[sl], batch.filters.equal[sl]]))
+
+
+class _Echo:
+    """A scheduler that completes whatever was submitted at its next
+    pump."""
+
+    def __init__(self):
+        self.q = []
+
+    def submit(self, req, now):
+        self.q.append(req)
+
+    def has_work(self):
+        return bool(self.q)
+
+    def pump(self, now):
+        done, self.q = self.q, []
+        return done, now
+
+
+def test_recall_set_is_fixed_and_replay_follows_the_pool():
+    cell = manifest.load_cell(CELL)
+    cfg = harness.deep_merge(cell.config, {"data": {"n": 1024}})
+    mix, seconds = cell.traffic, 51.0
+    dep = data.generate(cfg["data"], cfg["deployment_seed"])
+    gen = TrafficGen(mix, dep)
+    n_recall = recall_requests(mix, seconds)
+    pool = closed_pool(mix, seconds)
+    assert (n_recall, pool) == (480, 1148)
+    a, _ = harness.window_requests(mix, gen, cfg["deployment_seed"],
+                                   2**31 + 3, seconds)
+    b, _ = harness.window_requests(mix, gen, cfg["deployment_seed"],
+                                   3_900_000_007, seconds)
+    first = slice(0, n_recall)
+    assert not np.array_equal(a.queries[first], b.queries[first])
+    assert _rows(a, first) == _rows(b, first)
+
+    small = a.take(np.arange(40))
+    subs, sent, done = harness.serve_closed(_Echo(), small, 8, 0.05)
+    assert len(subs) > 2 * 40 and np.isfinite(done).all()
+    assert [r.rid for r in subs] == list(range(len(subs)))
+    for j, r in enumerate(subs):
+        assert r.expr is small.exprs[j % 40]
+        np.testing.assert_array_equal(r.query, small.queries[j % 40])
