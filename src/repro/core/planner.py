@@ -436,8 +436,7 @@ def _plan_reports(engine, cfg, state, plan, pred, pre_probe, probe_ndc,
 def _scan_part(engine, cfg, queries, prog, stats, lanes, base_state=None):
     return scan_search(
         engine, cfg, queries[lanes], prog.slice(lanes),
-        stats=ScanStats(valid=stats.valid[lanes], counts=stats.counts[lanes],
-                        clause_frac=stats.clause_frac[lanes], n=stats.n),
+        stats=stats.rows(lanes),
         base_state=base_state)
 
 

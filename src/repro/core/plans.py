@@ -33,6 +33,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,7 +41,7 @@ from repro.core.engine import SearchEngine
 from repro.core.search import SearchConfig, SearchState
 from repro.core.state import INF
 from repro.filters.compile import (CLAUSE_FEATURE_SLOTS, FilterProgram,
-                                   eval_program_matrix)
+                                   bitmap_chunk, eval_program_matrix)
 from repro.kernels import ops as kops
 from repro.kernels.distance import SCAN_ALIGN
 
@@ -49,7 +50,7 @@ class ScanStats(NamedTuple):
     """Bitmap-stage output: the scan plan's input and the planner's exact
     pre-probe statistics (σ_q and global per-clause selectivities)."""
 
-    valid: np.ndarray        # [B, N] bool candidate bitmap
+    valid: jax.Array         # [B, N] bool candidate bitmap, on the device
     counts: np.ndarray       # [B] i64 — σ_q·N, exact
     clause_frac: np.ndarray  # [B, CLAUSE_FEATURE_SLOTS] f32 global clause σ
     n: int                   # corpus size N
@@ -61,18 +62,19 @@ class ScanStats(NamedTuple):
     def rows(self, idx) -> "ScanStats":
         """Lane subset (planner partition / serving batch slicing)."""
         idx = np.asarray(idx)
-        return ScanStats(valid=self.valid[idx], counts=self.counts[idx],
+        return ScanStats(valid=jnp.take(self.valid, idx, axis=0),
+                         counts=self.counts[idx],
                          clause_frac=self.clause_frac[idx], n=self.n)
 
 
 def scan_stats(engine: SearchEngine, prog: FilterProgram,
-               chunk: int = 2048, tracer=None, trace_id: str = "",
-               ) -> ScanStats:
+               tracer=None, trace_id: str = "") -> ScanStats:
     """Compile the candidate bitmap + exact selectivity statistics.
 
     `tracer` spans the bitmap pass as `filter-bitmap`: the rows, the
-    chunks evaluated one after another, the lanes, and the bytes copied
-    device to host (the bitmap and each pass's clause counts)."""
+    chunks the pass walks inside its program, the lanes, and the bytes
+    copied device to host (each pass's counts and clause counts; the
+    bitmap stays on the device)."""
     from repro.obs.trace import as_tracer
 
     with as_tracer(tracer).span("filter-bitmap", trace_id) as sp:
@@ -80,19 +82,20 @@ def scan_stats(engine: SearchEngine, prog: FilterProgram,
             # index-axis-sharded engine: per-shard bitmap passes, one
             # global ScanStats (core.sharded) — keeps the planner
             # engine-agnostic
-            stats = engine.scan_stats(prog, chunk=chunk)
-            passes, chunks = engine.n_shards, engine.n_shards * -(
-                -engine.shard_size // chunk)
+            stats = engine.scan_stats(prog)
+            ns = engine.shard_size
+            passes = engine.n_shards
+            chunks = passes * -(-ns // bitmap_chunk(prog, ns))
         else:
-            valid, frac = eval_program_matrix(prog, engine.label_attrs,
-                                              engine.value_attrs, chunk=chunk)
-            stats = ScanStats(valid=valid,
-                              counts=valid.sum(axis=1).astype(np.int64),
-                              clause_frac=frac, n=int(valid.shape[1]))
-            passes, chunks = 1, -(-stats.n // chunk)
-        sp.set(rows=stats.n, chunks=chunks, lanes=int(stats.valid.shape[0]),
-               bytes_to_host=int(stats.valid.nbytes
-                                 + passes * stats.clause_frac.nbytes))
+            valid, counts, frac = eval_program_matrix(
+                prog, engine.label_attrs, engine.value_attrs)
+            stats = ScanStats(valid=valid, counts=counts, clause_frac=frac,
+                              n=int(valid.shape[1]))
+            passes, chunks = 1, -(-stats.n // bitmap_chunk(prog, stats.n))
+        lanes = int(stats.valid.shape[0])
+        # per pass: int32 counts [B] and clause counts [B, slots]
+        sp.set(rows=stats.n, chunks=chunks, lanes=lanes,
+               bytes_to_host=passes * lanes * 4 * (1 + CLAUSE_FEATURE_SLOTS))
     return stats
 
 

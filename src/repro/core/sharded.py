@@ -591,7 +591,7 @@ class ShardedSearchEngine:
         return out
 
     # ------------------------------------------------------------- scan ----
-    def scan_stats(self, prog: FilterProgram, chunk: int = 2048):
+    def scan_stats(self, prog: FilterProgram):
         """Global ScanStats assembled from per-shard bitmap passes.
 
         counts is exactly the sum of per-shard counts (each the popcount of
@@ -600,12 +600,11 @@ class ShardedSearchEngine:
         """
         from repro.core.plans import ScanStats, scan_stats
 
-        per = [scan_stats(e, prog, chunk=chunk) for e in self.shards]
-        valid = np.concatenate([p.valid for p in per], axis=1)
+        per = [scan_stats(e, prog) for e in self.shards]
         frac = np.sum([p.clause_frac * p.n for p in per], axis=0)
         frac = (frac / max(self.n, 1)).astype(np.float32)
-        return ScanStats(valid=valid,
-                         counts=valid.sum(axis=1).astype(np.int64),
+        return ScanStats(valid=jnp.concatenate([p.valid for p in per], axis=1),
+                         counts=np.sum([p.counts for p in per], axis=0),
                          clause_frac=frac, n=self.n)
 
     def scan(self, cfg: SearchConfig, queries, filt, stats=None,
@@ -630,7 +629,7 @@ class ShardedSearchEngine:
             lo = int(self.offsets[i])
             sl = stats.valid[:, lo:lo + ns]
             sstats = ScanStats(valid=sl,
-                               counts=sl.sum(axis=1).astype(np.int64),
+                               counts=np.asarray(sl.sum(axis=1), np.int64),
                                clause_frac=stats.clause_frac, n=ns)
             bs = (None if base_state is None
                   else take_shard(base_state.shard, i))

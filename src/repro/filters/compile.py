@@ -32,6 +32,7 @@ evaluates to False everywhere; `pad_program` produces such rows.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import jax
@@ -62,6 +63,12 @@ CLAUSE_FEATURE_SLOTS = 4
 # Hard ceiling on compiled slots — masks ride in uint32 words and the
 # per-clause counter path packs slot bits into an int32 lane on TPU.
 MAX_SLOTS = 32
+
+# Elements of one step's [B, S, rows, W] intermediates in the full-store
+# bitmap pass. Each step is a dozen device ops, and a profiler trace
+# records every one: a narrow program walks 2^20 rows in a few steps,
+# a wide one in more, smaller steps that stay bounded in memory.
+BITMAP_STEP_ELEMENTS = 1 << 22
 
 
 class FilterProgram(NamedTuple):
@@ -291,52 +298,81 @@ def eval_program_gathered(prog: FilterProgram, labels_g, values_g):
     return jnp.any(term_ok, axis=1), clause_sat
 
 
-@jax.jit
-def _matrix_chunk(prog: FilterProgram, labels, values):
-    """One N-chunk of the full-store evaluation: (valid [B,nb], clause
-    counts [B, CLAUSE_FEATURE_SLOTS])."""
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _matrix_pass(prog: FilterProgram, labels, values, chunk: int):
+    """The whole full-store evaluation as one program: (valid [B, N],
+    counts [B], clause counts [B, CLAUSE_FEATURE_SLOTS]).
+
+    N pads up to a chunk multiple and `lax.map` walks the [N/chunk, chunk,
+    W] views, so the [B, S, chunk, W] intermediates stay bounded. Pad rows
+    are masked out of `valid` and of every count by their row id: a
+    negated clause would match a zero label word."""
     b = prog.kinds.shape[0]
-    nb = labels.shape[0]
-    lg = jnp.broadcast_to(labels[None], (b, nb, labels.shape[1]))
-    vg = jnp.broadcast_to(values[None], (b, nb, values.shape[1]))
-    valid, csat = eval_program_gathered(prog, lg, vg)
-    return valid, clause_counts(csat, jnp.ones_like(valid))
+    n = labels.shape[0]
+    n_chunks = -(-n // chunk)
+    pad = n_chunks * chunk - n
+    lab = jnp.pad(labels, ((0, pad), (0, 0))).reshape(n_chunks, chunk, -1)
+    val = jnp.pad(values, ((0, pad), (0, 0))).reshape(n_chunks, chunk, -1)
+
+    def one(xs):
+        start, lc, vc = xs
+        live = jnp.broadcast_to(start + jnp.arange(chunk) < n, (b, chunk))
+        lg = jnp.broadcast_to(lc[None], (b,) + lc.shape)
+        vg = jnp.broadcast_to(vc[None], (b,) + vc.shape)
+        valid, csat = eval_program_gathered(prog, lg, vg)
+        valid = valid & live
+        return (valid, valid.sum(axis=1, dtype=jnp.int32),
+                clause_counts(csat, live))
+
+    starts = jnp.arange(n_chunks, dtype=jnp.int32) * chunk
+    valid, counts, cc = jax.lax.map(one, (starts, lab, val))
+    valid = jnp.moveaxis(valid, 0, 1).reshape(b, n_chunks * chunk)[:, :n]
+    return valid, counts.sum(axis=0), cc.sum(axis=0)
+
+
+def bitmap_chunk(prog: FilterProgram, n: int) -> int:
+    """Rows per step of the bitmap pass of `prog` over n rows: the power
+    of two (at least 1024) that keeps a step near BITMAP_STEP_ELEMENTS,
+    and no more than n, so a small store is one step with no pad rows."""
+    b, s, w = prog.masks.shape
+    rows = max(1024, BITMAP_STEP_ELEMENTS // (b * s * w))
+    return min(n, 1 << (rows.bit_length() - 1))
 
 
 def eval_program_matrix(prog: FilterProgram, labels, values,
-                        chunk: int = 2048):
+                        chunk: int | None = None):
     """Evaluate a program batch against the *full* attribute store.
 
     prog leaves [B, S, ...]; labels [N, W] u32; values [N, V] f32 →
-    (valid [B, N] bool, clause_frac [B, CLAUSE_FEATURE_SLOTS] f32).
+    (valid [B, N] bool on the device, counts [B] i64 and clause_frac
+    [B, CLAUSE_FEATURE_SLOTS] f32 on the host).
 
     This is the scan plan's candidate-bitmap compiler and the planner's
-    exact per-query selectivity source: `valid.sum(1)/N` is σ_q with no
+    exact per-query selectivity source: `counts / N` is σ_q with no
     sampling error, and `clause_frac` is the *global* analogue of the
     probe's rho_clause_* features (clause satisfaction over the whole
-    store instead of over the probe's inspected set). Chunked over N
-    because eval_program_gathered materializes [B, S, nb, W]
-    intermediates. Boolean evaluation only — no distances, so per the
-    repo's NDC accounting (predicate evaluations are tracked separately
-    in n_inspected) compiling the bitmap costs 0 NDC, like every other
-    predicate evaluation in the traversal. Results are exact and
-    per-lane independent: lane b's row depends only on its own program
-    row, which the serving layer's batch-composition guarantees rely on.
+    store instead of over the probe's inspected set). One jitted pass per
+    (B, S, N) shape, chunked over N inside the program because
+    eval_program_gathered materializes [B, S, chunk, W] intermediates;
+    `chunk` defaults to `bitmap_chunk`.
+    The bitmap stays on the device (only the scan plan reads it); the
+    counts and clause counts are the one device-to-host copy. Boolean
+    evaluation only — no distances, so per the repo's NDC accounting
+    (predicate evaluations are tracked separately in n_inspected)
+    compiling the bitmap costs 0 NDC, like every other predicate
+    evaluation in the traversal. Results are exact and per-lane
+    independent: lane b's row depends only on its own program row, which
+    the serving layer's batch-composition guarantees rely on.
     """
-    labels = jnp.asarray(labels)
     values = jnp.asarray(values)
     if values.ndim == 1:
         values = values[:, None]
-    n = labels.shape[0]
-    outs, counts = [], None
-    for s in range(0, n, chunk):
-        valid, cc = _matrix_chunk(prog, labels[s:s + chunk],
-                                  values[s:s + chunk])
-        outs.append(np.asarray(valid))
-        counts = cc if counts is None else counts + cc
-    valid = np.concatenate(outs, axis=1)
-    frac = np.asarray(counts, np.float32) / float(n)
-    return valid, frac
+    n = int(labels.shape[0])
+    valid, counts, cc = _matrix_pass(prog, jnp.asarray(labels), values,
+                                     chunk=chunk or bitmap_chunk(prog, n))
+    counts, cc = jax.device_get((counts, cc))
+    frac = np.asarray(cc, np.float32) / float(n)
+    return valid, counts.astype(np.int64), frac
 
 
 def clause_counts(clause_sat, counted, n_slots: int = CLAUSE_FEATURE_SLOTS):

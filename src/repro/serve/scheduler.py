@@ -640,7 +640,7 @@ class CostAwareScheduler:
             prog = self.batcher.pad_program(reqs, width)
             if stats is not None and pad:
                 stats = ScanStats(
-                    valid=np.pad(stats.valid, ((0, pad), (0, 0))),
+                    valid=jnp.pad(stats.valid, ((0, pad), (0, 0))),
                     counts=np.pad(stats.counts, (0, pad)),
                     clause_frac=np.pad(stats.clause_frac,
                                        ((0, pad), (0, 0))),

@@ -124,6 +124,116 @@ def test_scan_pallas_kernel_matches_host(world):
         scan_sqdist_lanes(q, x[:, : SCAN_ALIGN + 1], mask[:, : SCAN_ALIGN + 1])
 
 
+def _bitmap_exprs(ds, b):
+    """b filters cycling through every clause kind, negations included:
+    a bare Not matches the zero label words and values of pad rows."""
+    from repro.filters import Equal, In, Not, Or
+    from repro.filters.expr import labels_from_mask
+
+    own = labels_from_mask(np.asarray(ds.labels_packed)[7])
+    pool = [Not(Range(0.2, 0.7, attr=1)), Equal(own), In([2, 5, 30]),
+            And(In([3, 4]), Not(Contain([9]))), Contain([1]),
+            Range(0.2, 0.7), Not(Equal(own)),
+            Or(Range(-1e9, 0.1), And(Equal(own), Not(In([0])))),
+            Not(In([0, 1, 2, 3])), Range(1e9, 1e9 + 1),
+            Or(Contain([2, 7]), Not(Range(0.3, 0.6)), In([11])),
+            And(Range(0.1, 0.9), Range(0.0, 0.5, attr=1), Not(Contain([4]))),
+            Not(And(In([6]), Range(0.4, 1.0))), Contain([12]),
+            Or(Not(Equal(own)), Range(0.0, 0.01)), In(list(range(32)))]
+    return [pool[i % len(pool)] for i in range(b)]
+
+
+@pytest.mark.parametrize("chunk", [384, 2048, None])
+@pytest.mark.parametrize("b", [1, 4, 16])
+def test_bitmap_pass_matches_host_oracle(world, b, chunk):
+    """The one-pass bitmap equals the host oracle bit for bit, with N
+    (2500) no multiple of an explicit chunk, and in the default one step:
+    `valid` against bruteforce.valid_mask, counts its popcount, and
+    clause_frac each canonical slot's literal counted over the store on
+    the host."""
+    from repro.filters.compile import CLAUSE_FEATURE_SLOTS, eval_program_matrix
+    from repro.filters.expr import canonical_dnf, eval_leaf
+    from repro.index.bruteforce import valid_mask
+
+    ds, engine, _ = world
+    exprs = _bitmap_exprs(ds, b)
+    labels, values = np.asarray(ds.labels_packed), np.asarray(ds.value_matrix)
+    n = labels.shape[0]
+    assert chunk is None or n % chunk
+    valid, counts, frac = eval_program_matrix(
+        engine.compile(exprs), engine.label_attrs, engine.value_attrs,
+        chunk=chunk)
+
+    want = valid_mask(exprs, labels, values)
+    assert np.array_equal(np.asarray(valid), want)
+    assert counts.dtype == np.int64
+    assert np.array_equal(counts, want.sum(axis=1))
+    cc = np.zeros((b, CLAUSE_FEATURE_SLOTS), np.int64)
+    for i, e in enumerate(exprs):
+        lits = [lit for term in canonical_dnf(e) for lit in term]
+        for j, (leaf, neg) in enumerate(lits[:CLAUSE_FEATURE_SLOTS]):
+            cc[i, j] = (eval_leaf(leaf, labels, values) ^ neg).sum()
+    ref = np.asarray(cc, np.float32) / float(n)
+    assert np.array_equal(frac.view(np.uint32), ref.view(np.uint32))
+
+
+@pytest.mark.parametrize("b,slots,words,n,want", [
+    (16, 1, 1, 1 << 20, 1 << 18),     # the served cell: 4 steps
+    (4, 1, 1, 1 << 20, 1 << 20),      # one step, capped at N
+    (16, 1, 1, 2500, 2500),           # a small store: one step, no pad
+    (16, 32, 4, 1 << 20, 2048),       # the widest program: smaller steps
+    (256, 32, 4, 1 << 20, 1024),      # floor
+])
+def test_bitmap_chunk_bounds_the_step(b, slots, words, n, want):
+    """The bitmap pass's rows per step follow the program's shape: a
+    power of two keeping [B, S, rows, W] near BITMAP_STEP_ELEMENTS, at
+    least 1024 rows and at most N."""
+    from repro.filters import Equal
+    from repro.filters.compile import (BITMAP_STEP_ELEMENTS, bitmap_chunk,
+                                       compile_filters, pad_program)
+
+    prog = pad_program(compile_filters([Equal([0])], words), n_slots=slots,
+                       batch=b)
+    assert prog.masks.shape == (b, slots, words)
+    rows = bitmap_chunk(prog, n)
+    assert rows == want
+    assert b * slots * words * rows <= max(BITMAP_STEP_ELEMENTS,
+                                           b * slots * words * 1024)
+
+
+def test_scan_stats_leave_bitmap_on_device(world, monkeypatch):
+    """Stage 0 copies only the counts to the host: the [B, N] bitmap is a
+    device array, and the one explicit copy holds the counts [B] and the
+    clause counts [B, CLAUSE_FEATURE_SLOTS]. The guard refuses implicit
+    copies where the backend enforces it (accelerators; not the CPU)."""
+    import jax
+
+    from repro.filters import compile as fc
+
+    fetched, real_get = [], jax.device_get
+
+    def spy(x):
+        fetched.extend(np.shape(a) for a in jax.tree.leaves(x))
+        return real_get(x)
+
+    monkeypatch.setattr(fc.jax, "device_get", spy)
+    ds, engine, _ = world
+    exprs = _bitmap_exprs(ds, 4)
+    prog = engine.compile(exprs)
+    with jax.transfer_guard_device_to_host("disallow"):
+        stats = scan_stats(engine, prog)
+    monkeypatch.undo()
+    assert fetched == [(4,), (4, fc.CLAUSE_FEATURE_SLOTS)]
+    assert isinstance(stats.valid, jax.Array)
+    assert isinstance(stats.counts, np.ndarray)
+    assert isinstance(stats.clause_frac, np.ndarray)
+    sub = stats.rows(np.array([3, 0]))
+    assert isinstance(sub.valid, jax.Array)
+    want = np.asarray(stats.valid)[[3, 0]]
+    assert np.array_equal(np.asarray(sub.valid), want)
+    assert np.array_equal(sub.counts, want.sum(axis=1))
+
+
 def test_scan_lane_and_width_invariance(world):
     """A lane's scan result is independent of batchmates and of the padded
     gather width — the property serving-time batch shapes rely on."""
